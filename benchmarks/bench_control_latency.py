@@ -84,10 +84,6 @@ def _run_leg(eng, edge, k):
 
 
 def _mitigation_latency_rows():
-    try:
-        import jax  # noqa: F401
-    except ImportError:                  # container without jax
-        return []
     n = common.smoke(20_000, 2_500)
     rows = []
     for k in common.smoke((4, 8, 16), (8,)):

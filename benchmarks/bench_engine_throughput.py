@@ -210,11 +210,7 @@ def _monitored_rows():
         ]
         oracle = None
         for mode, opts in variants:
-            try:
-                tps, sink, span = _run_monitored(n, num_workers, chunk,
-                                                 **opts)
-            except ImportError:
-                continue            # container without jax
+            tps, sink, span = _run_monitored(n, num_workers, chunk, **opts)
             if oracle is None:
                 oracle = sink.counts.copy()
             else:
@@ -322,11 +318,8 @@ def _rowstate_rows():
     rows = []
     for num_workers, chunk, n in shapes:
         for name, build in (("join", _build_join), ("sort", _build_sort)):
-            try:
-                ref_tps, ref_sink = _time_build(build, n, num_workers,
-                                                chunk, reference=True)
-            except ImportError:
-                continue
+            ref_tps, ref_sink = _time_build(build, n, num_workers, chunk,
+                                            reference=True)
             variants = [
                 (f"{name}_reference", dict()),
                 (f"{name}_numpy", dict(backend="numpy")),
@@ -351,11 +344,8 @@ def _rowstate_rows():
                 if mode.endswith("_reference"):
                     tps, sink = ref_tps, ref_sink
                 else:
-                    try:
-                        tps, sink = _time_build(build, n, num_workers,
-                                                chunk, **opts)
-                    except ImportError:
-                        continue        # container without jax
+                    tps, sink = _time_build(build, n, num_workers, chunk,
+                                            **opts)
                 assert np.array_equal(sink.counts, ref_sink.counts), mode
                 row = dict(mode=mode, n_tuples=n, workers=num_workers,
                            chunk=chunk, tuples_per_sec=round(tps),
@@ -426,12 +416,8 @@ def _chain_rows(n: int, num_workers: int = 16, chunk: int = 512):
     rows = []
     oracle = {}
     for mode, with_project, opts in variants:
-        try:
-            tps, sink, per_super = _run_chain(n, num_workers, chunk,
-                                              with_project=with_project,
-                                              **opts)
-        except ImportError:
-            continue                # container without jax
+        tps, sink, per_super = _run_chain(n, num_workers, chunk,
+                                          with_project=with_project, **opts)
         if with_project in oracle:
             assert np.array_equal(sink.counts, oracle[with_project]), mode
         else:
@@ -475,10 +461,7 @@ def run(n_tuples: int = 200_000, include_pallas: bool = True) -> None:
                     device_executor="jit", n=min(n_tuples, jit_n))))
         for mode, opts in variants:
             n = opts.pop("n", n_tuples)
-            try:
-                tps, sink = _run_one(n, num_workers, chunk, **opts)
-            except ImportError:
-                continue            # container without jax
+            tps, sink = _run_one(n, num_workers, chunk, **opts)
             ref_tps, ref_sink = base(n)   # honest same-n baseline
             assert np.array_equal(sink.counts, ref_sink.counts), mode
             rows.append(dict(

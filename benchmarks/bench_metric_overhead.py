@@ -46,11 +46,7 @@ def _device_plane_rows(scale, workers, batch_ticks=8):
     armed — the armed controller turns W-per-round host traffic into
     boundary-only drain readbacks.  GroupByAgg is the monitored op: the
     in-dispatch controller refuses W1's REPLICATE-migrating probe."""
-    try:
-        import jax  # noqa: F401
-        import numpy as np
-    except ImportError:                  # container without jax
-        return []
+    import numpy as np
     n = int(200_000 * scale)
     num_keys = 64
     rng = np.random.default_rng(0)

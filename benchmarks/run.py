@@ -92,13 +92,7 @@ def _smoke_check(name: str) -> str:
         if not chaos or int(chaos[0]["identical"]) != 1:
             return f"{name}: chaos run not bit-identical"
     if name == "control_latency":
-        # the mitigation-latency pair (PR 6) lands in its own table;
-        # required whenever the container has jax (the bench emits it
-        # only when the device plane is importable)
-        try:
-            import jax  # noqa: F401
-        except ImportError:
-            return ""
+        # the mitigation-latency pair lands in its own table
         mpath = os.path.join(common.RESULTS_DIR,
                              "control_latency_mitigation.smoke.csv")
         if not os.path.exists(mpath):
@@ -118,6 +112,10 @@ def main() -> int:
                     help="tiny sizes; assert every bench runs + emits "
                          "valid tables (CI bit-rot guard)")
     args = ap.parse_args()
+    if not args.smoke:
+        # smoke runs tiny CPU compiles as a test; they leave no cache
+        from repro.launch.compile_cache import use_compile_cache
+        use_compile_cache()
     if args.smoke:
         os.environ["REPRO_BENCH_SMOKE"] = "1"
         # Smoke doubles as the sanitizer leg: retrace sentinel, mirror

@@ -106,11 +106,17 @@ an attached :class:`~repro.core.controller.ReshapeController` is *armed*
 onto its monitored edge (:class:`DeviceController`): per-key arrival
 stats, the workload tracker, the skew test, helper choice and the
 phase-1 / phase-2 split-ratio math are compiled into a ``ctrl_step``
-that runs **inside the fused dispatch plane** — all metric rounds a
-super-tick covers execute in one jitted call, and a detection rewrites
-the routing constants (cdf32 / primary / split mask / owner) as device
-arrays with a bumped device-side epoch, so the very next window
-dispatches rebalanced without a host boundary.  The host controller
+that runs all metric rounds a super-tick covers in one jitted call, and
+a detection rewrites the routing constants (cdf32 / primary / split
+mask / owner) with a bumped epoch, so the very next window dispatches
+rebalanced without a round of the host controller.  The ``ctrl_step``
+runs on the host's CPU backend (:func:`_ctrl_device`): a TPU has no
+IEEE binary64, and its float64 emulation rounds the observation scaling
+and the split weights unlike the host controller (which can move a
+float32 CDF entry by an ulp), so the bit-exact twin would be lost.  On
+a TPU every super-tick with a metric round therefore crosses the host
+boundary: it reads the arrivals back from the chip and uploads the
+routing constants (O(K + W) values each way).  The host controller
 stays the **bit-exact twin and arbitration point**: each round's
 observation window (phi, owner-attributed arrivals) is logged on
 device, and at the next boundary :meth:`DeviceController.drain` replays
@@ -128,7 +134,8 @@ pinned helpers — MARKERS / REPLICATE operators (HashJoinProbe) and
 multi-helper or delayed-control configs refuse up front and stay
 host-stepped.  An armed controller *demotes* back to host stepping the
 moment device-held state stops being authoritative: a host-side state
-mutation (scattered-state merge at END, ``mark_state_stale``), an
+mutation (``mark_state_stale``; at END it instead *retires* before the
+scattered-state merge, drained and without an incident), an
 out-of-band routing rewrite (another writer bumping ``table.version``),
 or a checkpoint restore carrying mitigation state the jit twin cannot
 represent (anything outside PHASE_ONE / PHASE_TWO, or pending delayed
@@ -166,10 +173,22 @@ counters and every integer metric are identical across the jit step, the
 host twin and the reference plane (the routing core is the canonical
 rule of :mod:`repro.core.partitioner`; placement and budgeted pops are
 integer arithmetic).  Float64 val payloads round-trip untouched through
-rings and maps; only the *summation order* of keyed float folds may
-differ from numpy's sequential weighted ``bincount`` (XLA scatter-add),
-which is why the engine's cross-plane contract is stated on
-``Sink.series`` / ``Sink.counts`` (integers) and checkpoint counters.
+rings, maps and row stores: the device carries them as their int64 bit
+patterns (a TPU has no binary64 -- XLA stores float64 there as a pair
+of float32 and would round every value on upload) and reads them as
+float64 only where it computes on them (predicates, sums).  Float sums
+may differ: the *summation order* of keyed float folds differs from
+numpy's sequential weighted ``bincount`` (XLA scatter-add), and a TPU's
+float64 arithmetic is emulated, which is why the engine's cross-plane
+contract is stated on ``Sink.series`` / ``Sink.counts`` (integers) and
+checkpoint counters.  That contract holds only while every Filter
+predicate decides as numpy would, and two cases break it, because the
+predicate computes on the float64 XLA gives it: every XLA backend (the
+CPU too) reads a subnormal value as zero; and on a TPU the float32 pair
+keeps about 48 significant bits and float32's exponent range, so a value
+within about 2**-48 (relative) of a threshold, or of magnitude below
+about 1.2e-38 or above 3.4e38, can be kept or dropped unlike on the
+numpy plane.  Sink counts and series then differ with it.
 
 Memory tiering (watermark spill of cold device state)
 -----------------------------------------------------
@@ -272,6 +291,14 @@ MAX_FOLD_CELLS = 1 << 22
 #: and is unaffected).
 MAX_SERVICE_RATE = 1 << 20
 
+#: routing works on blocks of lanes whose [lanes, K] or [lanes, W]
+#: temporaries hold at most this many cells; split counters count with
+#: one-hot prefix sums while K is at most ``ONEHOT_MAX_KEYS`` and sort
+#: beyond.  A TPU compiles a 2**20-lane sort in about 90 s, the blocked
+#: prefix sums in about 4 s.
+ONEHOT_BLOCK_CELLS = 1 << 21
+ONEHOT_MAX_KEYS = 2048
+
 #: probe-expand ceiling: the emit buffer is W * B * M lanes (M = the max
 #: per-tuple build-match fanout, so it always covers the worst case and
 #: carry-over never has to defer outputs past the host plane's tick);
@@ -295,13 +322,68 @@ def _note_trace(kind, spec, args) -> None:
 
 
 def _x64():
-    from jax.experimental import enable_x64
-    return enable_x64()
-
-
-def _interpret() -> bool:
     import jax
-    return jax.default_backend() != "tpu"
+    return jax.enable_x64(True)
+
+
+def _ctrl_device():
+    """Where the controller step runs and keeps its state: the host's CPU
+    backend.  XLA lowers float64 on a TPU to pairs of float32 (about 48
+    significant bits), so the step's non-integer arithmetic -- observation
+    scaling, means, stderr, shares, split ratios -- would not round like
+    the host controller's IEEE binary64, and the bit-exact twin would be
+    lost.  Per super-tick the step moves O(K + W) values each way.
+    None when ``JAX_PLATFORMS`` leaves the CPU backend out."""
+    import jax
+    try:
+        return jax.devices("cpu")[0]
+    except RuntimeError:
+        return None
+
+
+def _data_device():
+    """The device the data-plane steps run on (JAX's default)."""
+    import jax
+    return jax.devices()[0]
+
+
+def _val_bits(vals) -> np.ndarray:
+    """Host float64 values -> the int64 bit patterns the device carries."""
+    return np.asarray(vals, np.float64).view(np.int64)
+
+
+def _val_floats(bits) -> np.ndarray:
+    """Device-carried value bits -> host float64 values."""
+    return np.asarray(bits).view(np.float64)
+
+
+def _as_f64(bits):
+    """In a step: carried value bits -> float64, for arithmetic only.
+    (On a TPU the result is the float32-pair emulation, and a predicate
+    on it can decide unlike numpy near a threshold -- see the module's
+    bit-exactness notes; the carried bits are never rewritten from it.)"""
+    import jax
+    return jax.lax.bitcast_convert_type(bits, _jnp().float64)
+
+
+def _as_bits(x):
+    """In a step: computed float64 -> carried bits (a Project's output).
+    XLA cannot lower this bitcast on a TPU, so :func:`wireable` keeps
+    Project on the host plane there."""
+    import jax
+    jnp = _jnp()
+    return jax.lax.bitcast_convert_type(x.astype(jnp.float64), jnp.int64)
+
+
+def _trace_errors() -> tuple:
+    """The errors a user fn that cannot be traced raises while a step
+    traces — the only first-dispatch failures that demote an edge.
+    Lowering and compile refusals, runtime faults, OOM and a tracer
+    leaked by the plane's own step code propagate."""
+    import jax
+    e = jax.errors
+    return (e.ConcretizationTypeError, e.TracerArrayConversionError,
+            e.TracerIntegerConversionError, e.NonConcreteBooleanIndexError)
 
 
 def resolve_executor(requested: Optional[str]) -> str:
@@ -328,12 +410,18 @@ def wireable(op, num_keys: int) -> bool:
     per-(worker, key) structures — keyed folds, the probe match table —
     keep wide key spaces host-side.  This is the full paper operator
     set: Filter / Project / GroupByAgg / Sink plus the row-state
-    HashJoinBuild / HashJoinProbe / RangeSort.
+    HashJoinBuild / HashJoinProbe / RangeSort -- less Project on a TPU,
+    where its computed values cannot be turned back into the bit
+    patterns the plane carries.
     """
+    import jax
+
     from .operators import (Filter, GroupByAgg, HashJoinBuild,
                             HashJoinProbe, Project, RangeSort, Sink)
     if type(op) not in (Filter, Project, GroupByAgg, Sink,
                         HashJoinBuild, HashJoinProbe, RangeSort):
+        return False
+    if type(op) is Project and jax.default_backend() == "tpu":
         return False
     # Row-state operators keep no dense [W, K] structure (their state is
     # a [W, rcap] row log), so only the K-sized routing consts gate them
@@ -361,14 +449,14 @@ class DeviceChunk:
     """
 
     keys: object                 # [NB] int64 jnp
-    vals: object                 # [NB] float64 jnp
+    vals: object                 # [NB] int64 jnp: float64 bit patterns
     valid: object                # [NB] bool jnp
     n_live: int
 
     def to_host(self) -> Chunk:
         """Materialize + compact (the device -> host plane boundary)."""
         m = np.asarray(self.valid)
-        return (np.asarray(self.keys)[m], np.asarray(self.vals)[m])
+        return (np.asarray(self.keys)[m], _val_floats(self.vals)[m])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -400,6 +488,10 @@ def _split_counters(spec: StepSpec, consts, count, keys, valid):
     import jax
     jnp = _jnp()
     live = valid & consts["is_split"][keys]
+    if spec.K <= ONEHOT_MAX_KEYS:
+        occ, seen = _occurrences(keys, live, spec.K)
+        counters = jnp.where(live, count[keys] + occ.astype(count.dtype), 0)
+        return counters, count + seen.astype(count.dtype)
     n = keys.shape[0]
     arange = jnp.arange(n, dtype=count.dtype)
     sent = jnp.where(live, keys, spec.K)          # dead lanes sort last
@@ -411,6 +503,55 @@ def _split_counters(spec: StepSpec, consts, count, keys, valid):
     counters = jnp.where(live, count[keys] + occ, 0)
     new_count = count.at[keys].add(live.astype(count.dtype))
     return counters, new_count
+
+
+def _lane_blocks(width: int, *cols):
+    """Pad lane columns of length n and fold them to ``[nblk, b]`` blocks,
+    b chosen so a ``[b, width]`` temporary stays within
+    ``ONEHOT_BLOCK_CELLS``: a padded chunk may hold tens of millions of
+    lanes, and a whole-chunk ``[n, width]`` temporary would not fit the
+    chip's memory.  Padding lanes must be masked dead by the caller."""
+    jnp = _jnp()
+    n = cols[0].shape[0]
+    b = min(n, max(1, ONEHOT_BLOCK_CELLS // width))
+    nblk = -(-n // b)
+    return tuple(jnp.pad(c, (0, nblk * b - n)).reshape(nblk, b)
+                 for c in cols)
+
+
+def _occurrences(ids, live, n_ids: int):
+    """For each live lane, how many live lanes with its id come before it
+    (the rank a stable sort by id would give), and the live count per id
+    -- without a sort: one-hot prefix sums over blocks of lanes, carrying
+    the per-id counts from block to block."""
+    import jax
+    jnp = _jnp()
+    n = ids.shape[0]
+    every = jnp.arange(n_ids, dtype=ids.dtype)
+
+    def block(seen, blk):
+        i, m = blk
+        oh = ((i[:, None] == every[None, :]) & m[:, None]).astype(jnp.int32)
+        before = jnp.cumsum(oh, axis=0) - oh + seen[None, :]
+        return (seen + oh.sum(axis=0, dtype=jnp.int32),
+                (before * oh).sum(axis=1, dtype=jnp.int32))
+
+    seen, occ = jax.lax.scan(block, jnp.zeros(n_ids, jnp.int32),
+                             _lane_blocks(n_ids, ids, live))
+    return occ.reshape(-1)[:n], seen
+
+
+def _inverse_cdf(cdf, keys, u):
+    """The canonical rule's raw destination, ``#{w : u >= cdf[key, w]}``,
+    lane by lane over blocks of lanes."""
+    import jax
+    jnp = _jnp()
+    n = keys.shape[0]
+    dest = jax.lax.map(
+        lambda ku: jnp.sum(ku[1][:, None] >= cdf[ku[0]], axis=1,
+                           dtype=jnp.int32),
+        _lane_blocks(cdf.shape[1], keys, u))
+    return dest.reshape(-1)[:n]
 
 
 def _advance_and_route(spec: StepSpec, consts, count, keys, valid):
@@ -430,12 +571,11 @@ def _advance_and_route(spec: StepSpec, consts, count, keys, valid):
             kpart = importlib.import_module("repro.kernels.partition")
             kdest, _, _ = kpart.partition_scatter(
                 keys.astype(jnp.int32), counters.astype(jnp.int32),
-                consts["cdf"], cdf=consts["cdf"], interpret=_interpret())
+                consts["cdf"], cdf=consts["cdf"])
             dest = kdest.astype(keys.dtype)
         else:
             u = ld_thresholds(counters)
-            dest = jnp.sum(u[:, None] >= consts["cdf"][keys],
-                           axis=1).astype(keys.dtype)
+            dest = _inverse_cdf(consts["cdf"], keys, u).astype(keys.dtype)
             dest = jnp.minimum(dest, spec.W - 1)
             dest = jnp.where(consts["is_split"][keys], dest,
                              consts["primary"][keys])
@@ -444,11 +584,9 @@ def _advance_and_route(spec: StepSpec, consts, count, keys, valid):
         # low-discrepancy sequence is not consumed (host policy).
         dest = consts["primary"][keys]
         new_count = count
-    onehot = ((dest[:, None] == jnp.arange(spec.W, dtype=dest.dtype)[None, :])
-              & valid[:, None]).astype(count.dtype)
-    hist = onehot.sum(axis=0)
-    rank = ((jnp.cumsum(onehot, axis=0) - onehot) * onehot).sum(axis=1)
-    return dest, rank, hist, new_count
+    rank, hist = _occurrences(dest, valid, spec.W)
+    return (dest, rank.astype(count.dtype), hist.astype(count.dtype),
+            new_count)
 
 
 def _push(spec: StepSpec, state, keys, vals, valid, dest, rank, hist):
@@ -512,9 +650,8 @@ def _ingest_rows_kernel(spec: StepSpec, consts, state, chunk):
     kpart = importlib.import_module("repro.kernels.partition")
     kdest, krank, khist, kcnt, _ = kpart.partition_scatter_fold(
         keys.astype(jnp.int32), counters.astype(jnp.int32),
-        vals.astype(jnp.float32), consts["cdf"],
-        valid=valid.astype(jnp.int32), cdf=consts["cdf"],
-        interpret=_interpret())
+        _as_f64(vals).astype(jnp.float32), consts["cdf"],
+        valid=valid.astype(jnp.int32), cdf=consts["cdf"])
     dest = kdest.astype(keys.dtype)
     rank = krank.astype(keys.dtype)
     hist = khist.astype(state["count"].dtype)
@@ -551,14 +688,14 @@ def _push_placed(spec: StepSpec, state, ok, ov, keep, hist):
 
 def _map_stage(spec: StepSpec, wk, wv, wmask):
     """Apply a Filter predicate / Project map to a popped ``[W, B]``
-    window; returns (out_keys, out_vals, keep)."""
+    window of value bits; returns (out_keys, out_vals, keep)."""
     if spec.kind == "filter":
-        keep = wmask & spec.fn(wk, wv).astype(bool)
+        keep = wmask & spec.fn(wk, _as_f64(wv)).astype(bool)
         ok, ov = wk, wv
     else:                                   # project
-        ok, ov = spec.fn(wk, wv)
+        ok, ov = spec.fn(wk, _as_f64(wv))
         ok = ok.astype(wk.dtype)
-        ov = ov.astype(wv.dtype)
+        ov = _as_bits(ov)
         keep = wmask
     return ok, ov, keep
 
@@ -611,7 +748,7 @@ def _fold_popped(spec: StepSpec, consts, state, wk, wv, wmask):
     m_own = wmask & owned
     m_scat = wmask & ~owned
     flat = (wid * spec.K + wk).reshape(-1)
-    wvf = wv.reshape(-1)
+    wvf = _as_f64(wv).reshape(-1)
 
     def fold(cnt, sm, pres, m):
         mf = m.reshape(-1)
@@ -710,7 +847,7 @@ def _make_step_chain():
                         counts=st["counts"].at[kf].add(
                             mf.astype(st["counts"].dtype)),
                         sums=st["sums"].at[kf].add(
-                            jnp.where(mf, ov.reshape(-1), 0.0)))
+                            jnp.where(mf, _as_f64(ov).reshape(-1), 0.0)))
                     metrics.append((hist, None, None))
                     carry = None
                     continue
@@ -748,7 +885,8 @@ def _make_step_sink():
     def step(spec: StepSpec, consts, state, chunk):
         _note_trace("sink", spec, (consts, state, chunk))
         jnp = _jnp()
-        keys, vals, valid = chunk
+        keys, bits, valid = chunk
+        vals = _as_f64(bits)
         state = _fold_stats(spec, state, keys, valid)
         if spec.use_kernel:
             # Fused partition_scatter_fold kernel: per-key counts + sums
@@ -760,8 +898,7 @@ def _make_step_sink():
             _, _, _, kcnt, ksm = kpart.partition_scatter_fold(
                 keys.astype(jnp.int32), jnp.zeros(keys.shape, jnp.int32),
                 vals.astype(jnp.float32), ones,
-                valid=valid.astype(jnp.int32), cdf=ones,
-                interpret=_interpret())
+                valid=valid.astype(jnp.int32), cdf=ones)
             counts = state["counts"] + kcnt.astype(state["counts"].dtype)
             sums = state["sums"] + ksm.astype(state["sums"].dtype)
         else:
@@ -1216,6 +1353,8 @@ class DeviceController:
             return "sink"
         if rt.W < 2:
             return "single worker"
+        if _ctrl_device() is None:
+            return "no CPU backend for the controller step"
         return None
 
     @property
@@ -1269,7 +1408,7 @@ class DeviceController:
             retire_window=int(retire),
             enable_phase1=bool(cfg.enable_phase1),
             horizon=float(host.tracker.horizon))
-        jnp = _jnp()
+        import jax
         table = rt.routing
         window = int(cfg.sample_window)
         obs = np.zeros((rt.W, window))
@@ -1293,25 +1432,22 @@ class DeviceController:
             mit_seq[s] = seq
         with _x64():
             rt._refresh_consts(force=True)
-            self.cstate = dict(
-                weights=jnp.asarray(table.weights.copy(), jnp.float64),
+            # Host arrays go straight to the controller's device: a detour
+            # through the data device would round the float64 ones.
+            self.cstate = jax.device_put(dict(
+                weights=np.array(table.weights, np.float64),
                 cdf=rt.consts["cdf"], primary=rt.consts["primary"],
                 is_split=rt.consts["is_split"], owner=rt.consts["owner"],
-                obs=jnp.asarray(obs, jnp.float64),
-                obs_n=jnp.asarray(obs_n, jnp.int32),
-                obs_pos=jnp.asarray(obs_pos, jnp.int32),
-                tau=jnp.asarray(float(host.tau), jnp.float64),
-                tau_adj=jnp.asarray(int(host.tau_adjustments), jnp.int32),
-                mit_active=jnp.asarray(mit_active, bool),
-                mit_helper=jnp.asarray(mit_helper, jnp.int32),
-                mit_phase=jnp.asarray(mit_phase, jnp.int32),
-                mit_calm=jnp.asarray(mit_calm, jnp.int32),
-                mit_seq=jnp.asarray(mit_seq, jnp.int32),
-                seq_next=jnp.asarray(len(host.mitigations), jnp.int32),
-                epoch=jnp.asarray(0, jnp.int32),
-                log_phi=jnp.zeros((self.LOG_CAP, rt.W), jnp.float64),
-                log_arr=jnp.zeros((self.LOG_CAP, rt.W), jnp.float64),
-                log_n=jnp.asarray(0, jnp.int32))
+                obs=obs, obs_n=obs_n, obs_pos=obs_pos,
+                tau=np.float64(host.tau),
+                tau_adj=np.int32(host.tau_adjustments),
+                mit_active=mit_active, mit_helper=mit_helper,
+                mit_phase=mit_phase, mit_calm=mit_calm, mit_seq=mit_seq,
+                seq_next=np.int32(len(host.mitigations)),
+                epoch=np.int32(0),
+                log_phi=np.zeros((self.LOG_CAP, rt.W), np.float64),
+                log_arr=np.zeros((self.LOG_CAP, rt.W), np.float64),
+                log_n=np.int32(0)), _ctrl_device())
         self.meta = []
         self.epoch_host = self.epoch_synced = 0
         self._last_tick = host._tick
@@ -1345,20 +1481,25 @@ class DeviceController:
             self.spec = dataclasses.replace(self.spec, KMAX=int(k))
         left = float(host.adapter.tuples_left())
         rate = float(host.adapter.processing_rate())
-        jnp = _jnp()
+        import jax
         step = _step_for("ctrl")
         with _x64():
             arrived = (rt.state["arrived"] if rt.state is not None
-                       else jnp.zeros(rt.K, jnp.int64))
-            phi = jnp.asarray(rt.workloads(), jnp.float64)
-            c, drained = step(self.spec, self.cstate, arrived, phi,
+                       else np.zeros(rt.K, np.int64))
+            cpu = _ctrl_device()
+            c, drained = step(self.spec, self.cstate,
+                              jax.device_put(arrived, cpu),
+                              jax.device_put(np.asarray(rt.workloads(),
+                                                        np.float64), cpu),
                               np.int64(t0), np.int64(k),
                               np.float64(left), np.float64(rate))
-        self.cstate = c
-        if rt.state is not None:
-            rt.state["arrived"] = drained
-        rt.consts = dict(cdf=c["cdf"], primary=c["primary"],
-                         is_split=c["is_split"], owner=c["owner"])
+            self.cstate = c
+            dev = _data_device()
+            if rt.state is not None:
+                rt.state["arrived"] = jax.device_put(drained, dev)
+            rt.consts = jax.device_put(
+                dict(cdf=c["cdf"], primary=c["primary"],
+                     is_split=c["is_split"], owner=c["owner"]), dev)
         self.meta.append((t0, k, left, rate))
         self.epoch_host = int(np.asarray(c["epoch"]))
         host.rounds_on_device += len(fired)
@@ -1400,7 +1541,7 @@ class DeviceController:
         # must equal the device's decision bit-for-bit; on mismatch the
         # host wins and the device consts are re-uploaded from it.
         table._refresh_derived()
-        jnp = _jnp()
+        import jax
         ok = (np.array_equal(np.asarray(self.cstate["weights"]),
                              table.weights)
               and np.array_equal(np.asarray(self.cstate["cdf"]),
@@ -1422,18 +1563,18 @@ class DeviceController:
                     cause="in-dispatch decisions diverged from the "
                           "host twin",
                     action="host wins; device consts re-uploaded")
-                self.cstate = dict(
-                    self.cstate,
-                    weights=jnp.asarray(table.weights.copy(), jnp.float64),
-                    cdf=jnp.asarray(table.cdf32, jnp.float32),
-                    primary=jnp.asarray(table._primary, jnp.int64),
-                    is_split=jnp.asarray(table._is_split, bool))
-            self.cstate = dict(self.cstate,
-                               log_n=jnp.asarray(0, jnp.int32))
-        rt.consts = dict(cdf=self.cstate["cdf"],
-                         primary=self.cstate["primary"],
-                         is_split=self.cstate["is_split"],
-                         owner=self.cstate["owner"])
+                self.cstate.update(jax.device_put(dict(
+                    weights=np.array(table.weights, np.float64),
+                    cdf=np.asarray(table.cdf32, np.float32),
+                    primary=np.asarray(table._primary, np.int64),
+                    is_split=np.asarray(table._is_split, bool)),
+                    _ctrl_device()))
+            self.cstate["log_n"] = jax.device_put(np.int32(0),
+                                                  _ctrl_device())
+            rt.consts = jax.device_put(
+                {k: self.cstate[k]
+                 for k in ("cdf", "primary", "is_split", "owner")},
+                _data_device())
         rt._consts_version = table.version
         rt._consts_split = bool(table._any_split)
         self.epoch_synced = self.epoch_host
@@ -1473,6 +1614,15 @@ class DeviceController:
                 cause=reason, action="host-stepped controller resumes")
         self.active = False
         self.reason = reason
+
+    def retire(self) -> None:
+        """END: no metric round remains, so hand the last decisions to the
+        host twin and stop before the END merge mutates host state.  Not
+        a demotion: nothing falls back to host stepping."""
+        if self.active:
+            self.drain()
+            self.active = False
+            self.reason = "end"
 
     def on_restore(self) -> None:
         """Checkpoint restore: in-flight device decisions die with the
@@ -1735,14 +1885,14 @@ class DeviceOpRuntime:
         jnp = _jnp()
         n = int(keys.shape[0])
         pk = np.zeros(self.NB, np.int64)
-        pv = np.zeros(self.NB, np.float64)
+        pv = np.zeros(self.NB, np.int64)
         m = np.zeros(self.NB, bool)
         pk[:n] = keys
-        pv[:n] = vals
+        pv[:n] = _val_bits(vals)
         m[:n] = True
         with _x64():
             return DeviceChunk(jnp.asarray(pk, jnp.int64),
-                               jnp.asarray(pv, jnp.float64),
+                               jnp.asarray(pv, jnp.int64),
                                jnp.asarray(m, bool), n)
 
     # ---- device state lifecycle --------------------------------------- #
@@ -1754,7 +1904,7 @@ class DeviceOpRuntime:
                       totals=jnp.zeros(self.K, jnp.int64))
             if self.kind != "sink":
                 st.update(rk=jnp.zeros((self.W, self.cap), jnp.int64),
-                          rv=jnp.zeros((self.W, self.cap), jnp.float64),
+                          rv=jnp.zeros((self.W, self.cap), jnp.int64),
                           head=jnp.zeros(self.W, jnp.int64),
                           tail=jnp.zeros(self.W, jnp.int64))
             if self.kind == "fold":
@@ -1768,7 +1918,7 @@ class DeviceOpRuntime:
                 st["mcounts"] = jnp.zeros((self.W, self.K), jnp.int64)
             if self.kind == "rows":
                 st.update(bk=jnp.zeros((self.W, self.rcap), jnp.int64),
-                          bv=jnp.zeros((self.W, self.rcap), jnp.float64),
+                          bv=jnp.zeros((self.W, self.rcap), jnp.int64),
                           bo=jnp.zeros((self.W, self.rcap), bool),
                           rlen=jnp.zeros(self.W, jnp.int64))
             if self.kind == "sink":
@@ -1800,19 +1950,19 @@ class DeviceOpRuntime:
         with _x64():
             if self.kind != "sink":
                 rk = np.zeros((self.W, self.cap), np.int64)
-                rv = np.zeros((self.W, self.cap), np.float64)
+                rv = np.zeros((self.W, self.cap), np.int64)
                 for w, worker in enumerate(op.workers):
                     k, v = worker.queue.snapshot()
                     if v.ndim != 1:
                         raise ValueError("device plane requires 1-D vals")
                     ln = int(k.size)
                     rk[w, :ln] = k
-                    rv[w, :ln] = v
+                    rv[w, :ln] = _val_bits(v)
                     self.lens[w] = ln
                     self.received[w] = worker.queue.received_total
                 self.state.update(
                     rk=jnp.asarray(rk, jnp.int64),
-                    rv=jnp.asarray(rv, jnp.float64),
+                    rv=jnp.asarray(rv, jnp.int64),
                     head=jnp.zeros(self.W, jnp.int64),
                     tail=jnp.asarray(self.lens.copy(), jnp.int64))
             if self.kind == "fold":
@@ -1848,21 +1998,21 @@ class DeviceOpRuntime:
                 if need + self.B > self.rcap:
                     self.rcap = _pow2(2 * max(need + self.B, 1))
                 bk = np.zeros((self.W, self.rcap), np.int64)
-                bv = np.zeros((self.W, self.rcap), np.float64)
+                bv = np.zeros((self.W, self.rcap), np.int64)
                 bo = np.zeros((self.W, self.rcap), bool)
                 for w, worker in enumerate(op.workers):
                     ok_k, ok_v = worker.state.export_rows()
                     sc_k, sc_v = worker.scattered.export_rows()
                     n1, n2 = int(ok_k.size), int(sc_k.size)
                     bk[w, :n1] = ok_k
-                    bv[w, :n1] = ok_v
+                    bv[w, :n1] = _val_bits(ok_v)
                     bo[w, :n1] = True
                     bk[w, n1:n1 + n2] = sc_k
-                    bv[w, n1:n1 + n2] = sc_v
+                    bv[w, n1:n1 + n2] = _val_bits(sc_v)
                     self.rows_len[w] = n1 + n2
                 self.state.update(
                     bk=jnp.asarray(bk, jnp.int64),
-                    bv=jnp.asarray(bv, jnp.float64),
+                    bv=jnp.asarray(bv, jnp.int64),
                     bo=jnp.asarray(bo, bool),
                     rlen=jnp.asarray(self.rows_len.copy(), jnp.int64))
             if self.kind == "sink":
@@ -1951,7 +2101,7 @@ class DeviceOpRuntime:
         head = np.asarray(self.state["head"])
         old_cap = rk_np.shape[1]
         new_k = np.zeros((self.W, self.cap), np.int64)
-        new_v = np.zeros((self.W, self.cap), np.float64)
+        new_v = np.zeros((self.W, self.cap), np.int64)
         resident = self.lens - self.spilled_lens
         for w in range(self.W):
             ln = int(resident[w])
@@ -1960,7 +2110,7 @@ class DeviceOpRuntime:
             new_v[w, :ln] = rv_np[w, idx]
         with _x64():
             self.state.update(rk=jnp.asarray(new_k, jnp.int64),
-                              rv=jnp.asarray(new_v, jnp.float64),
+                              rv=jnp.asarray(new_v, jnp.int64),
                               head=jnp.zeros(self.W, jnp.int64),
                               tail=jnp.asarray(resident.copy(),
                                                jnp.int64))
@@ -1974,14 +2124,14 @@ class DeviceOpRuntime:
         bo = np.asarray(self.state["bo"])
         old = bk.shape[1]
         new_k = np.zeros((self.W, self.rcap), np.int64)
-        new_v = np.zeros((self.W, self.rcap), np.float64)
+        new_v = np.zeros((self.W, self.rcap), np.int64)
         new_o = np.zeros((self.W, self.rcap), bool)
         new_k[:, :old] = bk
         new_v[:, :old] = bv
         new_o[:, :old] = bo
         with _x64():
             self.state.update(bk=jnp.asarray(new_k, jnp.int64),
-                              bv=jnp.asarray(new_v, jnp.float64),
+                              bv=jnp.asarray(new_v, jnp.int64),
                               bo=jnp.asarray(new_o, bool))
 
     # ---- spill tier (memory tiering; see module docstring) ------------- #
@@ -2038,7 +2188,7 @@ class DeviceOpRuntime:
                     self.state["rk"] = self.state["rk"].at[w, idx].set(
                         jnp.asarray(k, jnp.int64))
                     self.state["rv"] = self.state["rv"].at[w, idx].set(
-                        jnp.asarray(v, jnp.float64))
+                        jnp.asarray(v, jnp.int64))
                     self.state["tail"] = self.state["tail"].at[w].add(
                         np.int64(seg.n))
                     self.spilled_lens[w] -= seg.n
@@ -2144,13 +2294,13 @@ class DeviceOpRuntime:
             bv[w, :left] = bv[w, m:rres]
             bo[w, :left] = bo[w, m:rres]
             bk[w, left:rres] = 0
-            bv[w, left:rres] = 0.0
+            bv[w, left:rres] = 0
             bo[w, left:rres] = False
             rlen[w] = left
             self.spilled_rows[w] += m
         with _x64():
             self.state.update(bk=jnp.asarray(bk, jnp.int64),
-                              bv=jnp.asarray(bv, jnp.float64),
+                              bv=jnp.asarray(bv, jnp.int64),
                               bo=jnp.asarray(bo, bool),
                               rlen=jnp.asarray(rlen, jnp.int64))
 
@@ -2314,16 +2464,11 @@ class DeviceOpRuntime:
             chunks, self.staged, self.staged_live = self.staged, [], 0
             return self._dispatch(_step_for(self.kind), self._spec(),
                                   chunks, budget)
-        except _sanitize.SanitizeError:
-            raise               # never masked as a host-path demotion
-        except Exception as exc:
+        except _trace_errors() as exc:
             if self._dispatched:
                 raise
-            # First-ever dispatch failed (typically an untraceable user
-            # fn): fall back to the host plane and replay this tick
-            # there.  The warning keeps genuine device-plane errors —
-            # OOM, version breakage — from being silently masked as a
-            # perf cliff.
+            # First-ever dispatch could not trace a user fn: fall back to
+            # the host plane and replay this tick there.
             import warnings
             warnings.warn(
                 f"device plane: first dispatch for {self.op.name!r} "
@@ -2402,9 +2547,7 @@ class DeviceOpRuntime:
                 if (int((self.lens - self.spilled_lens).sum()) == 0
                         and not self.staged):
                     break          # drained: further pops would take 0
-        except _sanitize.SanitizeError:
-            raise               # never masked as a host-path demotion
-        except Exception as exc:
+        except _trace_errors() as exc:
             if self._dispatched:
                 raise
             import warnings
@@ -2559,9 +2702,7 @@ class DeviceOpRuntime:
                 states_t, out, metrics = step(
                     specs, consts_t, states_t, dc,
                     tuple(np.int64(b) for b in budgets))
-        except _sanitize.SanitizeError:
-            raise               # never masked as a per-edge fallback
-        except Exception as exc:
+        except _trace_errors() as exc:
             if all(r._dispatched for r in members):
                 raise
             # First fused dispatch failed (typically an untraceable user
@@ -2758,7 +2899,7 @@ class DeviceOpRuntime:
         op = self.op
         if self.kind != "sink":
             rk = np.asarray(self.state["rk"])
-            rv = np.asarray(self.state["rv"])
+            rv = _val_floats(self.state["rv"])
             head = np.asarray(self.state["head"])
             for w, worker in enumerate(op.workers):
                 res = int(self.lens[w] - self.spilled_lens[w])
@@ -2774,7 +2915,8 @@ class DeviceOpRuntime:
                         self._spill_corrupt_incident(exc)
                         raise
                     k_w = np.concatenate([k_w] + [s.arrays[0] for s in segs])
-                    v_w = np.concatenate([v_w] + [s.arrays[1] for s in segs])
+                    v_w = np.concatenate(
+                        [v_w] + [_val_floats(s.arrays[1]) for s in segs])
                 worker.queue.restore((k_w, v_w), int(self.received[w]))
         if self.kind == "fold":
             cnt = np.asarray(self.state["counts"])
@@ -2793,7 +2935,7 @@ class DeviceOpRuntime:
             # scope's arrival order, so scope arrays are bit-identical
             # to the host plane's per-chunk segment appends.
             bk = np.asarray(self.state["bk"])
-            bv = np.asarray(self.state["bv"])
+            bv = _val_floats(self.state["bv"])
             bo = np.asarray(self.state["bo"])
             for w, worker in enumerate(op.workers):
                 n = int(self.rows_len[w] - self.spilled_rows[w])
@@ -2809,8 +2951,8 @@ class DeviceOpRuntime:
                         raise
                     k_w = np.concatenate([s.arrays[0] for s in segs]
                                          + [k_w])
-                    v_w = np.concatenate([s.arrays[1] for s in segs]
-                                         + [v_w])
+                    v_w = np.concatenate([_val_floats(s.arrays[1])
+                                          for s in segs] + [v_w])
                     o_w = np.concatenate([s.arrays[2] for s in segs]
                                          + [o_w])
                 worker.state.clear()

@@ -521,6 +521,9 @@ class Engine:
                 continue
             ups = self.upstreams.get(op.name, [])
             if ups and all(self._producer_done(u) for u in ups) and op.queues_empty():
+                dev = op.device
+                if dev is not None and dev.ctrl is not None:
+                    dev.ctrl.retire()
                 outs = op.on_end()
                 if outs and op.out_edge is not None:
                     op.out_edge.send(outs[0] if len(outs) == 1

@@ -261,38 +261,31 @@ class PallasPartitionBackend(PartitionBackend):
 
     name = "pallas"
 
-    def __init__(self, *, block_n: int = 1024,
+    def __init__(self, *, block_n: int = 512,
                  interpret: Optional[bool] = None):
-        try:
-            import jax  # noqa: F401  (gate: container may lack jax)
-            from ..kernels import partition as _  # noqa: F401
-        except Exception as exc:  # pragma: no cover - env without jax
-            raise ImportError(
-                "PallasPartitionBackend requires jax + the repro.kernels "
-                "package; use the 'numpy' backend instead") from exc
         self.block_n = int(block_n)
         self.interpret = interpret
 
     def _device_call(self, routing, keys, fn_name: str):
-        import jax
+        import importlib
+
         import jax.numpy as jnp
         from ..kernels import ops as kops
+        kpart = importlib.import_module("repro.kernels.partition")
 
         counters = routing.advance_counters(keys)
         interpret = (self.interpret if self.interpret is not None
-                     else jax.default_backend() != "tpu")
+                     else kpart.default_interpret())
         if interpret:
             # Off-TPU validation path: call the kernel module directly so
             # shapes of odd-sized tail chunks don't churn the jit cache.
-            import importlib
-            kpart = importlib.import_module("repro.kernels.partition")
             return getattr(kpart, fn_name)(
                 jnp.asarray(keys.astype(np.int32)),
                 jnp.asarray(counters.astype(np.int32)),
                 jnp.asarray(routing.weights),
                 cdf=jnp.asarray(routing.cdf32),
                 block_n=self.block_n, interpret=True)
-        return getattr(kops, fn_name)(  # pragma: no cover - TPU only
+        return getattr(kops, fn_name)(
             jnp.asarray(keys.astype(np.int32)),
             jnp.asarray(counters.astype(np.int32)),
             jnp.asarray(routing.weights),

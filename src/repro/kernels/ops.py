@@ -17,8 +17,7 @@ from . import rwkv_scan as _rwkv
 from . import segment_matmul as _segmm
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+_default_interpret = _part.default_interpret
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))
@@ -38,7 +37,7 @@ def rwkv_scan(r, k, v, w, u, state0=None):
 
 
 @functools.partial(jax.jit, static_argnames=("block_n",))
-def partition(keys, counters, weights, cdf=None, *, block_n: int = 1024):
+def partition(keys, counters, weights, cdf=None, *, block_n: int = 512):
     """Routing-table partition: (dest [N], histogram [W]).
 
     ``cdf`` optionally supplies the host-computed float32 row-CDF
@@ -50,7 +49,7 @@ def partition(keys, counters, weights, cdf=None, *, block_n: int = 1024):
 
 @functools.partial(jax.jit, static_argnames=("block_n",))
 def partition_scatter(keys, counters, weights, cdf=None, *,
-                      block_n: int = 1024):
+                      block_n: int = 512):
     """Fused exchange: (dest [N], within-destination rank [N], hist [W]).
 
     The rank output turns the scatter into a fancy-index placement at
@@ -63,7 +62,7 @@ def partition_scatter(keys, counters, weights, cdf=None, *,
 
 @functools.partial(jax.jit, static_argnames=("block_n",))
 def partition_scatter_fold(keys, counters, vals, weights, valid=None,
-                           cdf=None, *, block_n: int = 1024):
+                           cdf=None, *, block_n: int = 512):
     """Fully fused exchange + downstream fold (device-resident plane).
 
     (dest [N], rank [N], hist [W], fold_counts [K], fold_sums [K]) in one

@@ -52,13 +52,6 @@ _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 
 
-def _mesh_ctx(mesh):
-    """``jax.set_mesh`` appeared in jax 0.5; on 0.4.x the Mesh object is
-    itself the context manager with the same scoping semantics."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
-
-
 def _shape_bytes(text: str) -> int:
     total = 0
     for dt, dims in _SHAPE_RE.findall(text):
@@ -170,7 +163,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
     spec = SHAPES[shape_name]
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     t0 = time.time()
-    with _mesh_ctx(mesh):
+    with jax.set_mesh(mesh):
         fn, args, in_shardings = build_cell(cfg, shape_name, mesh)
         jitted = jax.jit(fn, in_shardings=in_shardings)
         lowered = jitted.lower(*args)
@@ -192,8 +185,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
                   "temp_size_in_bytes", "generated_code_size_in_bytes"):
             res[k] = int(getattr(ma, k, 0) or 0)
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):      # jax 0.4.x: one dict per program
-        ca = ca[0] if ca else {}
     if ca:
         # NOTE: xla cost_analysis does not multiply while bodies by trip
         # count; kept for reference only. The roofline uses hlo_analysis.

@@ -213,6 +213,25 @@ class TestLifecycle:
             assert dev._ctrl_refused == why
             _drive(b[0], 4)
 
+    def test_step_runs_on_the_cpu_backend_or_refuses(self, monkeypatch):
+        """The controller state lives on the host's CPU backend (a TPU's
+        float64 would break the bit-exact twin); without that backend the
+        controller stays host-stepped instead of arming."""
+        from repro.dataflow import device as devmod
+        b = _monitored("pallas", device_executor="jit",
+                       device_controller=True, n=600)
+        ctrl = b[0].controllers[0].op.device.ctrl
+        assert ctrl.active
+        assert {d.platform for d in ctrl.cstate["weights"].devices()} == {
+            "cpu"}
+        monkeypatch.setattr(devmod, "_ctrl_device", lambda: None)
+        b = _monitored("pallas", device_executor="jit",
+                       device_controller=True, n=600)
+        dev = b[0].controllers[0].op.device
+        assert dev.ctrl is None
+        assert dev._ctrl_refused == "no CPU backend for the controller step"
+        _drive(b[0], 4)
+
     def test_env_var_arms_controller(self, monkeypatch):
         monkeypatch.setenv("REPRO_DEVICE_CONTROLLER", "1")
         b = _monitored("pallas", device_executor="jit", n=600)

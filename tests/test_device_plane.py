@@ -38,6 +38,14 @@ def _half_pass(k, v):
     return v >= 5.0
 
 
+def _from_nine_tenths(k, v):
+    return v >= 0.9
+
+
+def _tiny_or_huge(k, v):
+    return ((v > 0.0) & (v < 1e-37)) | (v > 1e38)
+
+
 def _proj(k, v):
     return k, v * 2.0
 
@@ -52,8 +60,11 @@ def _zipf_stream(n, num_keys, seed=0, hot_frac=0.0):
 
 def _pipeline(backend=None, *, n=5000, num_keys=24, num_workers=4, chunk=8,
               batch_ticks=4, predicate=_all_pass, project=None,
-              controller=False, hot_frac=0.0, seed=0, **engine_kw):
+              controller=False, hot_frac=0.0, seed=0, pool=None,
+              **engine_kw):
     keys, vals = _zipf_stream(n, num_keys, seed, hot_frac)
+    if pool is not None:        # values drawn from a fixed set instead
+        vals = np.random.default_rng(seed + 1).choice(pool, n)
     eng = Engine(partition_backend=backend, batch_ticks=batch_ticks,
                  **engine_kw)
     src = eng.add_source(Source("src", keys, vals, num_workers * chunk))
@@ -103,6 +114,38 @@ class TestJitPlaneEquivalence:
                    for e in b[0].edges)
         _assert_runs_identical(a, b)
 
+    @pytest.mark.parametrize("block_cells", [1 << 21, 7 * 24],
+                             ids=["one-block", "padded-blocks"])
+    def test_split_counters_without_sort_match_sorted(self, block_cells,
+                                                      monkeypatch):
+        """The one-hot prefix-sum split counters equal the stable-sort
+        ones lane for lane, also over blocks whose last one is padded."""
+        import jax
+
+        from repro.dataflow import device as dev
+        K, n = 24, 1000
+        rng = np.random.default_rng(3)
+        keys = rng.integers(0, K, n)
+        valid = rng.random(n) < 0.8
+        is_split = np.zeros(K, bool)
+        is_split[[0, 3, 7, 11]] = True
+        count = rng.integers(0, 50, K)
+        spec = dev.StepSpec(kind="fold", W=4, K=K, cap=256, B=8,
+                            any_split=True, may_scatter=False,
+                            track_stats=False, use_kernel=False)
+        def run():
+            f = jax.jit(lambda s, c, k, v: dev._split_counters(
+                spec, {"is_split": s}, c, k, v))
+            return [np.asarray(x) for x in f(is_split, count, keys, valid)]
+
+        with jax.enable_x64(True):
+            monkeypatch.setattr(dev, "ONEHOT_BLOCK_CELLS", block_cells)
+            got = run()
+            monkeypatch.setattr(dev, "ONEHOT_MAX_KEYS", 0)
+            want = run()                            # the sort path
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)
+
     def test_groupby_state_identical(self):
         a = _pipeline("numpy", predicate=_half_pass)
         a[0].run()
@@ -117,6 +160,29 @@ class TestJitPlaneEquivalence:
                 assert wa.state[k][0] == wb.state[k][0]
                 assert wa.state[k][1] == pytest.approx(wb.state[k][1])
 
+    @pytest.mark.parametrize("predicate,pool", [
+        (_from_nine_tenths, [0.9, np.nextafter(0.9, 1.0),
+                             np.nextafter(0.9, 0.0), 0.9 * (1 + 2.0 ** -50),
+                             0.9 * (1 - 2.0 ** -50), 0.5]),
+        (_tiny_or_huge, [1e-39, 1e-300, np.finfo(np.float64).tiny,
+                         -1e-300, 0.0, -0.0, 3.5e38, 1e200, -1e200, 1.0]),
+    ], ids=["at-threshold", "beyond-float32"])
+    def test_filter_decisions_on_edge_values(self, predicate, pool):
+        """The plane carries values as bit patterns, so a predicate sees
+        every binary64 bit: values an ulp from its threshold and outside
+        float32's exponent range are kept exactly as on the numpy plane.
+        Not covered, because no XLA backend gives it: subnormal values,
+        which XLA reads as zero, and a TPU, where the predicate computes
+        on float64 emulated as a float32 pair (see the device module)."""
+        a = _pipeline("numpy", predicate=predicate, pool=pool)
+        a[0].run()
+        b = _pipeline("pallas", device_executor="jit", predicate=predicate,
+                      pool=pool)
+        b[0].run()
+        assert all(e.device_plane == "jit" for e in b[0].edges)
+        assert 0 < int(a[1].counts.sum()) < 5000
+        _assert_runs_identical(a, b)
+
     def test_project_stage_passthrough(self):
         a = _pipeline("numpy", project=_proj)
         a[0].run()
@@ -124,6 +190,21 @@ class TestJitPlaneEquivalence:
         b[0].run()
         assert all(e.device_plane == "jit" for e in b[0].edges)
         _assert_runs_identical(a, b)
+
+    @pytest.mark.parametrize("backend,wired", [("cpu", True),
+                                               ("tpu", False)])
+    def test_project_wired_only_where_float64_is_ieee(self, backend, wired,
+                                                      monkeypatch):
+        """A Project computes new values, which a TPU (float64 as a
+        float32 pair) cannot turn back into carried bit patterns: there
+        it stays on the host plane; Filter, which only reads, does not."""
+        import jax
+
+        from repro.dataflow import device as dev
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        proj = Project("p", 4, 32, fn=_proj)
+        assert dev.wireable(proj, 24) is wired
+        assert dev.wireable(Filter("f", 4, 32, predicate=_all_pass), 24)
 
     def test_controller_rewrites_and_migrations(self):
         """A Reshape controller on the device GroupBy: detections, the
@@ -538,6 +619,31 @@ class TestJitPlaneDemotion:
         np.testing.assert_array_equal(sink.counts,
                                       np.bincount(np.arange(50) % 8,
                                                   minlength=8))
+
+    @pytest.mark.parametrize("fused", [False, True],
+                             ids=["per-edge", "chain"])
+    def test_non_tracer_first_dispatch_error_propagates(self, fused,
+                                                        monkeypatch):
+        """Only tracer errors from a user fn demote: a compiler refusal
+        (here a Mosaic-style ValueError) at the first dispatch, per-edge
+        or fused, must surface instead of becoming a host-path run."""
+        from repro.dataflow import device as dev
+        target = "chain" if fused else "filter"
+        real = dev._step_for
+
+        def refusing(*args):
+            raise ValueError("Cannot do int indexing on TPU")
+
+        monkeypatch.setattr(
+            dev, "_step_for",
+            lambda kind: refusing if kind == target else real(kind))
+        eng = _pipeline("pallas", device_executor="jit", device_chain=fused)
+        eng = eng[0]
+        with pytest.raises(ValueError, match="int indexing"):
+            eng.run()
+        assert not eng.incidents.query("demotion")
+        assert not eng.incidents.query("chain-fallback")
+        assert all(e.device_plane == "jit" for e in eng.edges)
 
     def test_second_upstream_demotes(self):
         eng = Engine(partition_backend="pallas", device_executor="jit")

@@ -180,6 +180,34 @@ class TestRowStateEquivalence:
         _assert_runs_identical(a, b)
         _assert_row_state_identical(a[2], b[2])
 
+    def test_values_travel_as_bit_patterns(self):
+        """A TPU keeps float64 as a pair of float32 and rounds it on
+        upload, so the device plane carries values as their int64 bit
+        patterns: rings and row store hold int64, and values that need
+        all 53 significand bits, -0.0 and a subnormal read back with the
+        same bits."""
+        rng = np.random.default_rng(5)
+        vals = np.concatenate([rng.random(1_000) / 3.0,
+                               [-0.0, 5e-324, np.pi * 1e300]])
+        keys = rng.integers(0, NK, vals.size)
+        eng = Engine(partition_backend="pallas", batch_ticks=4,
+                     device_executor="jit")
+        src = eng.add_source(Source("src", keys, vals, 32))
+        bld = eng.add_op(HashJoinBuild("build", 4, 8))
+        eng.connect(src, bld, NK)
+        eng.run_super_tick(4)
+        assert bld.device.state["rv"].dtype == np.int64
+        assert bld.device.state["bv"].dtype == np.int64
+        eng.run()
+        assert all(e.device_plane == "jit" for e in eng.edges)
+        bld._device_sync()
+        got = np.concatenate([t.scope_array(int(k))
+                              for w in bld.workers
+                              for t in (w.state, w.scattered)
+                              for k in t.keys()])
+        np.testing.assert_array_equal(np.sort(got.view(np.int64)),
+                                      np.sort(vals.view(np.int64)))
+
     def test_join_controller_rewrites_and_migrations(self):
         """Reshape on the device probe (the W1 shape): detections,
         phase-1/2 rewrites, REPLICATE migrations of the build state and
