@@ -23,6 +23,9 @@ from typing import Callable, Dict, List, Optional, Protocol, Sequence, Tuple
 
 import numpy as np
 
+# absolute: pytest imports ``core`` as a top-level package when it
+# collects ``skew_test.py``, and ``..`` would then reach past it
+from repro import obs
 from . import adaptive_tau, load_transfer
 from .skew_test import assign_helpers
 from .estimator import WorkloadTracker
@@ -163,6 +166,7 @@ class ReshapeController:
         is set (the mitigation-latency knob)."""
         self.pressure_events.append((int(worker), int(tick)))
 
+    @obs.spanned("ctrl.step")
     def step(self, tick: int) -> None:
         """One controller round. Call every engine tick."""
         self._tick = tick

@@ -274,6 +274,7 @@ from typing import Callable, List, Optional, Union
 
 import numpy as np
 
+from .. import obs
 from ..analysis import sanitize as _sanitize
 from . import spill as spill_tier
 from .resilience import InjectedDispatchFault
@@ -345,6 +346,19 @@ def _data_device():
     """The device the data-plane steps run on (JAX's default)."""
     import jax
     return jax.devices()[0]
+
+
+def _readback(x, site: str) -> np.ndarray:
+    """Device -> host: ``np.asarray`` of a data-plane array.  An array on
+    the data device is counted (``device.readbacks``) and timed
+    (``device.readback``, naming its ``site``); host arrays pass through
+    uncounted."""
+    import jax
+    if not (isinstance(x, jax.Array) and x.devices() == {_data_device()}):
+        return np.asarray(x)
+    obs.count("device.readbacks")
+    with obs.span("device.readback", site=site):
+        return np.asarray(x)
 
 
 def _val_bits(vals) -> np.ndarray:
@@ -455,8 +469,9 @@ class DeviceChunk:
 
     def to_host(self) -> Chunk:
         """Materialize + compact (the device -> host plane boundary)."""
-        m = np.asarray(self.valid)
-        return (np.asarray(self.keys)[m], _val_floats(self.vals)[m])
+        m = _readback(self.valid, "chunk")
+        return (_readback(self.keys, "chunk")[m],
+                _val_floats(_readback(self.vals, "chunk"))[m])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -767,10 +782,20 @@ def _fold_popped(spec: StepSpec, consts, state, wk, wv, wmask):
                 scat_counts=scnt, scat_sums=ssm, scat_present=spres)
 
 
-def _make_step_fold():
+def _named(kind: str):
+    """Name a step function ``<kind>_step``, so that its jitted module reads
+    ``jit_<kind>_step`` in a profiler trace."""
+    def name(fn):
+        fn.__name__ = fn.__qualname__ = f"{kind}_step"
+        return fn
+    return name
+
+
+def _make_step_fold(kind: str):
     import jax
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+    @_named(kind)
     def step(spec: StepSpec, consts, state, chunk, budget):
         _note_trace("fold", spec, (consts, state, chunk, budget))
         jnp = _jnp()
@@ -788,10 +813,11 @@ def _make_step_fold():
     return step
 
 
-def _make_step_map():
+def _make_step_map(kind: str):
     import jax
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+    @_named(kind)
     def step(spec: StepSpec, consts, state, chunk, budget):
         _note_trace("map", spec, (consts, state, chunk, budget))
         jnp = _jnp()
@@ -811,7 +837,7 @@ def _make_step_map():
     return step
 
 
-def _make_step_chain():
+def _make_step_chain(kind: str):
     """One jitted dispatch advancing a whole fused chain: the head's
     ingest runs the chain's *single* partition + scatter; every later
     stage receives its predecessor's pre-placed ``[W, B]`` survivors
@@ -821,6 +847,7 @@ def _make_step_chain():
     import jax
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+    @_named(kind)
     def step(specs, consts_t, states_t, chunk, budgets):
         _note_trace("chain", specs, (consts_t, states_t, chunk, budgets))
         jnp = _jnp()
@@ -878,10 +905,11 @@ def _make_step_chain():
     return step
 
 
-def _make_step_sink():
+def _make_step_sink(kind: str):
     import jax
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+    @_named(kind)
     def step(spec: StepSpec, consts, state, chunk):
         _note_trace("sink", spec, (consts, state, chunk))
         jnp = _jnp()
@@ -914,9 +942,10 @@ _STEP_CACHE = {}
 
 
 def _step_for(kind: str):
-    """One persistent jitted step per operator family; the cache is
-    module-global so repeated engine builds retrace only on a genuinely
-    new :class:`StepSpec` (shape growth, rewrite arming, new user fn)."""
+    """One persistent jitted step per kind, named ``<kind>_step``; the
+    cache is module-global so repeated engine builds retrace only on a
+    genuinely new :class:`StepSpec` (shape growth, rewrite arming, new
+    user fn)."""
     if kind not in _STEP_CACHE:
         _STEP_CACHE[kind] = {"fold": _make_step_fold,
                              "rows": _make_step_fold,
@@ -925,7 +954,7 @@ def _step_for(kind: str):
                              "probe": _make_step_map,
                              "sink": _make_step_sink,
                              "chain": _make_step_chain,
-                             "ctrl": _make_ctrl_step}[kind]()
+                             "ctrl": _make_ctrl_step}[kind](kind)
     return _STEP_CACHE[kind]
 
 
@@ -963,7 +992,7 @@ class CtrlSpec:
     horizon: float             # tracker prediction horizon (tuples)
 
 
-def _make_ctrl_step():
+def _make_ctrl_step(kind: str):
     """Build the jitted ``controller_step``.
 
     One call covers one super-tick window ``[t0, t0+k)``: for every
@@ -984,6 +1013,7 @@ def _make_ctrl_step():
     PH2 = 3                    # MitigationPhase.PHASE_TWO.value
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
+    @_named(kind)
     def ctrl_step(cs: CtrlSpec, c, arrived, phi, t0, k, tuples_left, rate):
         _note_trace("ctrl", cs, (c, arrived, phi, t0, k,
                                  tuples_left, rate))
@@ -1456,6 +1486,7 @@ class DeviceController:
         return True
 
     # ---- the per-super-tick in-dispatch step ---------------------------
+    @obs.spanned("ctrl.super_tick")
     def super_tick(self, t0: int, k: int) -> None:
         host = self.host
         cfg = host.cfg
@@ -1487,24 +1518,29 @@ class DeviceController:
             arrived = (rt.state["arrived"] if rt.state is not None
                        else np.zeros(rt.K, np.int64))
             cpu = _ctrl_device()
-            c, drained = step(self.spec, self.cstate,
-                              jax.device_put(arrived, cpu),
-                              jax.device_put(np.asarray(rt.workloads(),
-                                                        np.float64), cpu),
-                              np.int64(t0), np.int64(k),
-                              np.float64(left), np.float64(rate))
-            self.cstate = c
-            dev = _data_device()
-            if rt.state is not None:
-                rt.state["arrived"] = jax.device_put(drained, dev)
-            rt.consts = jax.device_put(
-                dict(cdf=c["cdf"], primary=c["primary"],
-                     is_split=c["is_split"], owner=c["owner"]), dev)
+            arrived = jax.device_put(_readback(arrived, "ctrl.arrived"),
+                                     cpu)
+            # The step on the CPU backend, through the epoch read that
+            # waits for it (a wait on the host, not a chip readback).
+            with obs.span("ctrl.cpu_step"):
+                c, drained = step(self.spec, self.cstate, arrived,
+                                  jax.device_put(np.asarray(
+                                      rt.workloads(), np.float64), cpu),
+                                  np.int64(t0), np.int64(k),
+                                  np.float64(left), np.float64(rate))
+                self.cstate = c
+                dev = _data_device()
+                if rt.state is not None:
+                    rt.state["arrived"] = jax.device_put(drained, dev)
+                rt.consts = jax.device_put(
+                    dict(cdf=c["cdf"], primary=c["primary"],
+                         is_split=c["is_split"], owner=c["owner"]), dev)
+                self.epoch_host = int(np.asarray(c["epoch"]))
         self.meta.append((t0, k, left, rate))
-        self.epoch_host = int(np.asarray(c["epoch"]))
         host.rounds_on_device += len(fired)
 
     # ---- boundary drain: mirror decisions into the host twin -----------
+    @obs.spanned("ctrl.drain")
     def drain(self) -> None:
         if not self.active:
             return
@@ -1526,11 +1562,12 @@ class DeviceController:
         table.listener = None   # the device already routed post-rewrite
         host.adapter = shim
         try:
-            for (t0, k, left, rate), phi, arr in zip(meta, log_phi,
-                                                     log_arr):
-                shim.set_window(phi, arr, left, rate)
-                for t in range(t0, t0 + k):
-                    host.step(t)
+            with obs.span("ctrl.replay"):
+                for (t0, k, left, rate), phi, arr in zip(meta, log_phi,
+                                                         log_arr):
+                    shim.set_window(phi, arr, left, rate)
+                    for t in range(t0, t0 + k):
+                        host.step(t)
         finally:
             host.adapter = saved_adapter
             table.listener = saved_listener
@@ -1927,6 +1964,7 @@ class DeviceOpRuntime:
         self.state = st
         self._load_host_state()
 
+    @obs.spanned("device.reload")
     def _load_host_state(self) -> None:
         """Host -> device: (re)load keyed state, rings and mirrors from
         the operator's host structures (initial wiring, post-migration
@@ -2096,9 +2134,9 @@ class DeviceOpRuntime:
     def _regrow_rings(self) -> None:
         """Re-layout the rings at a larger capacity (content preserved)."""
         jnp = _jnp()
-        rk_np = np.asarray(self.state["rk"])
-        rv_np = np.asarray(self.state["rv"])
-        head = np.asarray(self.state["head"])
+        rk_np = _readback(self.state["rk"], "regrow")
+        rv_np = _readback(self.state["rv"], "regrow")
+        head = _readback(self.state["head"], "regrow")
         old_cap = rk_np.shape[1]
         new_k = np.zeros((self.W, self.cap), np.int64)
         new_v = np.zeros((self.W, self.cap), np.int64)
@@ -2119,9 +2157,9 @@ class DeviceOpRuntime:
         """Re-layout the flat row log at a larger capacity (append-only:
         no ring wrap, so regrowth is a prefix copy per column)."""
         jnp = _jnp()
-        bk = np.asarray(self.state["bk"])
-        bv = np.asarray(self.state["bv"])
-        bo = np.asarray(self.state["bo"])
+        bk = _readback(self.state["bk"], "regrow")
+        bv = _readback(self.state["bv"], "regrow")
+        bo = _readback(self.state["bo"], "regrow")
         old = bk.shape[1]
         new_k = np.zeros((self.W, self.rcap), np.int64)
         new_v = np.zeros((self.W, self.rcap), np.int64)
@@ -2182,7 +2220,7 @@ class DeviceOpRuntime:
                         self.cap = _pow2(2 * (res + seg.n + budget))
                         self._regrow_rings()
                     k, v = (seg.arrays if dev is None else dev)[:2]
-                    tail = int(np.asarray(self.state["tail"])[w])
+                    tail = int(_readback(self.state["tail"], "spill")[w])
                     idx = (tail + jnp.arange(seg.n, dtype=jnp.int64)
                            ) % self.cap
                     self.state["rk"] = self.state["rk"].at[w, idx].set(
@@ -2249,9 +2287,9 @@ class DeviceOpRuntime:
         segments, prepending at the spill front (they are logically just
         before any already-spilled span)."""
         jnp = _jnp()
-        rk = np.asarray(self.state["rk"])
-        rv = np.asarray(self.state["rv"])
-        head = np.asarray(self.state["head"])
+        rk = _readback(self.state["rk"], "spill")
+        rv = _readback(self.state["rv"], "spill")
+        head = _readback(self.state["head"], "spill")
         delta = np.zeros(self.W, np.int64)
         for w in ws:
             res = int(self.lens[w] - self.spilled_lens[w])
@@ -2277,10 +2315,10 @@ class DeviceOpRuntime:
         ``sync_host``, so the prefix is the coldest span by construction
         and never needs a mid-run re-upload."""
         jnp = _jnp()
-        bk = np.asarray(self.state["bk"]).copy()
-        bv = np.asarray(self.state["bv"]).copy()
-        bo = np.asarray(self.state["bo"]).copy()
-        rlen = np.asarray(self.state["rlen"]).copy()
+        bk = _readback(self.state["bk"], "spill").copy()
+        bv = _readback(self.state["bv"], "spill").copy()
+        bo = _readback(self.state["bo"], "spill").copy()
+        rlen = _readback(self.state["rlen"], "spill").copy()
         for w in ws:
             rres = int(self.rows_len[w] - self.spilled_rows[w])
             m = rres - int(keep)
@@ -2314,9 +2352,9 @@ class DeviceOpRuntime:
         if not ws:
             return
         jnp = _jnp()
-        rk = np.asarray(self.state["rk"])
-        rv = np.asarray(self.state["rv"])
-        head = np.asarray(self.state["head"])
+        rk = _readback(self.state["rk"], "spill")
+        rv = _readback(self.state["rv"], "spill")
+        head = _readback(self.state["head"], "spill")
         delta = np.zeros(self.W, np.int64)
         for w in ws:
             m = int(pushed[w])
@@ -2386,7 +2424,7 @@ class DeviceOpRuntime:
             self._consts_split = bool(rt._any_split)
 
     def _pull_counters(self) -> np.ndarray:
-        return np.asarray(self.state["count"])
+        return _readback(self.state["count"], "counters")
 
     def _claim_counters(self) -> None:
         rt = self.routing
@@ -2698,7 +2736,7 @@ class DeviceOpRuntime:
             consts_t = tuple(r.consts for r in members)
             states_t = tuple(r.state for r in members)
             step = _step_for("chain")
-            with _x64():
+            with _x64(), obs.span("device.dispatch"):
                 states_t, out, metrics = step(
                     specs, consts_t, states_t, dc,
                     tuple(np.int64(b) for b in budgets))
@@ -2735,20 +2773,21 @@ class DeviceOpRuntime:
             else:
                 r._placed_token = None
         for r, (hist, take, emitted) in zip(members, metrics):
-            hist = np.asarray(hist)
+            hist = _readback(hist, "hist")
             r.edge.exchange.account(hist)
             r.received += hist
             if take is None:            # sink tail: no rings, direct fold
                 r.op.workers[0].stats.processed_total += int(hist.sum())
             else:
-                take = np.asarray(take)
+                take = _readback(take, "take")
+                r._count_ring(hist)
                 r.lens += hist - take
                 if r.kind == "rows":    # every popped row was appended
                     r.rows_len += take
                 for w, worker in enumerate(r.op.workers):
                     worker.stats.processed_total += int(take[w])
             if emitted is not None:
-                em = np.asarray(emitted)
+                em = _readback(emitted, "emitted")
                 for w, worker in enumerate(r.op.workers):
                     worker.stats.emitted_total += int(em[w])
         for r in members[1:]:
@@ -2756,11 +2795,20 @@ class DeviceOpRuntime:
         if dc is not None:
             self.placements += 1        # the chain's single placement
         if out is not None:             # map tail: emit downstream
-            n_live = int(np.asarray(metrics[-1][2]).sum())
+            n_live = int(em.sum())      # the tail stage's emitted counts
             tail = members[-1]
             if n_live and tail.op.out_edge is not None:
                 tail.op.out_edge.send(DeviceChunk(*out, n_live))
         return []
+
+    def _count_ring(self, pushed: np.ndarray) -> None:
+        """Ring occupancy of one ring-holding dispatch, from the host
+        mirrors (call before they take the dispatch's pops): the slots
+        swept, ``W * cap``, and the records resident once this dispatch's
+        ``pushed`` records landed."""
+        obs.count("device.ring_slots", self.W * self.cap)
+        obs.count("device.ring_live",
+                  int((self.lens - self.spilled_lens).sum() + pushed.sum()))
 
     def flush_staged(self) -> None:
         """Route staged chunks into the rings without popping (budget 0).
@@ -2793,8 +2841,9 @@ class DeviceOpRuntime:
         with _x64():
             if self.kind == "sink":
                 for ch in chunks:      # received accounted at stage time
-                    self.state, _ = step(spec, self.consts, self.state,
-                                         (ch.keys, ch.vals, ch.valid))
+                    with obs.span("device.dispatch"):
+                        self.state, _ = step(spec, self.consts, self.state,
+                                             (ch.keys, ch.vals, ch.valid))
                     # The host-plane pop happens in this same tick slot.
                     self.op.workers[0].stats.processed_total += ch.n_live
                 self._dispatched = True
@@ -2806,8 +2855,9 @@ class DeviceOpRuntime:
             for ch, b in seq:
                 dc = (None if ch is None
                       else (ch.keys, ch.vals, ch.valid))
-                res = step(spec, self.consts, self.state, dc,
-                           np.int64(b))
+                with obs.span("device.dispatch"):
+                    res = step(spec, self.consts, self.state, dc,
+                               np.int64(b))
                 if ch is not None:
                     self.placements += 1
                 if self.kind in ("fold", "rows"):
@@ -2816,10 +2866,11 @@ class DeviceOpRuntime:
                 else:
                     self.state, out, (hist, take, emitted) = res
                 self._dispatched = True
-                hist = np.asarray(hist)
-                take = np.asarray(take)
+                hist = _readback(hist, "hist")
+                take = _readback(take, "take")
                 self.edge.exchange.account(hist)
                 self.received += hist
+                self._count_ring(hist)
                 self.lens += hist - take
                 pushed += hist
                 if self.kind == "rows":   # every popped row was appended
@@ -2827,7 +2878,7 @@ class DeviceOpRuntime:
                 for w, worker in enumerate(self.op.workers):
                     worker.stats.processed_total += int(take[w])
                 if emitted is not None:
-                    em = np.asarray(emitted)
+                    em = _readback(emitted, "emitted")
                     n_live = int(em.sum())
                     for w, worker in enumerate(self.op.workers):
                         worker.stats.emitted_total += int(em[w])
@@ -2859,16 +2910,19 @@ class DeviceOpRuntime:
         self.flush_staged()
         if self.state is None or self.op.arrived_by_key is None:
             return
-        a = np.asarray(self.state["arrived"])
+        a = _readback(self.state["arrived"], "stats")
+        t = None
         pending = a.any()
         if not pending and self.ctrl is not None:
             # The in-dispatch controller drains ``arrived`` itself (the
             # owner-aggregated copy feeds its estimators), but the
             # cumulative per-key totals still need to reach the host.
-            pending = bool(np.asarray(self.state["totals"]).any())
+            t = _readback(self.state["totals"], "stats")
+            pending = bool(t.any())
         if pending:
             jnp = _jnp()
-            t = np.asarray(self.state["totals"])
+            if t is None:
+                t = _readback(self.state["totals"], "stats")
             self.op.arrived_by_key += a
             self.op.key_arrivals_total += t
             with _x64():
@@ -2878,8 +2932,8 @@ class DeviceOpRuntime:
     def sync_sink_counts(self) -> None:
         """Sink-snapshot boundary: materialize the result columns only."""
         if self.state is not None:
-            self.op.counts[:] = np.asarray(self.state["counts"])
-            self.op.sums[:] = np.asarray(self.state["sums"])
+            self.op.counts[:] = _readback(self.state["counts"], "sink")
+            self.op.sums[:] = _readback(self.state["sums"], "sink")
 
     def sync_host(self) -> None:
         """Full device -> host materialization (checkpoint cut, END,
@@ -2896,11 +2950,17 @@ class DeviceOpRuntime:
             # has run since: the host copies are *ahead* of the device —
             # materializing now would clobber them with stale state.
             return
+        with obs.span("device.sync_host"):
+            self._materialize()
+
+    def _materialize(self) -> None:
+        """The body of :meth:`sync_host`: device state into the host
+        structures."""
         op = self.op
         if self.kind != "sink":
-            rk = np.asarray(self.state["rk"])
-            rv = _val_floats(self.state["rv"])
-            head = np.asarray(self.state["head"])
+            rk = _readback(self.state["rk"], "sync_host")
+            rv = _val_floats(_readback(self.state["rv"], "sync_host"))
+            head = _readback(self.state["head"], "sync_host")
             for w, worker in enumerate(op.workers):
                 res = int(self.lens[w] - self.spilled_lens[w])
                 idx = ring_span(head[w], res, self.cap)
@@ -2919,12 +2979,10 @@ class DeviceOpRuntime:
                         [v_w] + [_val_floats(s.arrays[1]) for s in segs])
                 worker.queue.restore((k_w, v_w), int(self.received[w]))
         if self.kind == "fold":
-            cnt = np.asarray(self.state["counts"])
-            sm = np.asarray(self.state["sums"])
-            pres = np.asarray(self.state["present"])
-            scnt = np.asarray(self.state["scat_counts"])
-            ssm = np.asarray(self.state["scat_sums"])
-            spres = np.asarray(self.state["scat_present"])
+            cnt, sm, pres, scnt, ssm, spres = (
+                _readback(self.state[name], "sync_host")
+                for name in ("counts", "sums", "present", "scat_counts",
+                             "scat_sums", "scat_present"))
             for w, worker in enumerate(op.workers):
                 worker.state.load_dense(cnt[w], sm[w], pres[w])
                 worker.scattered.load_dense(scnt[w], ssm[w], spres[w])
@@ -2934,9 +2992,9 @@ class DeviceOpRuntime:
             # stable grouping inside ``extend_segments`` preserves each
             # scope's arrival order, so scope arrays are bit-identical
             # to the host plane's per-chunk segment appends.
-            bk = np.asarray(self.state["bk"])
-            bv = _val_floats(self.state["bv"])
-            bo = np.asarray(self.state["bo"])
+            bk = _readback(self.state["bk"], "sync_host")
+            bv = _val_floats(_readback(self.state["bv"], "sync_host"))
+            bo = _readback(self.state["bo"], "sync_host")
             for w, worker in enumerate(op.workers):
                 n = int(self.rows_len[w] - self.spilled_rows[w])
                 k_w, v_w, o_w = bk[w, :n], bv[w, :n], bo[w, :n]
@@ -2984,8 +3042,8 @@ class DeviceOpRuntime:
             return
         problems = []
         if self.kind != "sink":
-            dev = (np.asarray(self.state["tail"])
-                   - np.asarray(self.state["head"]))
+            dev = (_readback(self.state["tail"], "sanitize")
+                   - _readback(self.state["head"], "sanitize"))
             resident = self.lens - self.spilled_lens
             if not np.array_equal(dev, resident):
                 problems.append((
@@ -2995,7 +3053,7 @@ class DeviceOpRuntime:
                     f"{self.spilled_lens.tolist()}) != device "
                     f"tail-head {dev.tolist()}"))
         if self.kind == "rows":
-            rlen = np.asarray(self.state["rlen"])
+            rlen = _readback(self.state["rlen"], "sanitize")
             rres = self.rows_len - self.spilled_rows
             if not np.array_equal(rlen, rres):
                 problems.append((
@@ -3019,7 +3077,8 @@ class DeviceOpRuntime:
                     f"{int(self.spilled_rows[w])}"))
         for name in ("sums", "scat_sums"):
             if name in self.state:
-                if not np.isfinite(np.asarray(self.state[name])).all():
+                if not np.isfinite(
+                        _readback(self.state[name], "sanitize")).all():
                     problems.append((
                         "sanitize-nan",
                         f"non-finite values in fold state {name!r}"))

@@ -76,6 +76,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs
 from ..core.controller import ReshapeController
 from ..core.partitioner import RoutingTable
 from ..core.state_migration import choose_strategy
@@ -477,6 +478,7 @@ class Engine:
         """One engine tick (the per-tick scheduler; == run_super_tick(1))."""
         self.run_super_tick(1)
 
+    @obs.spanned("engine.super_tick")
     def run_super_tick(self, k: int) -> None:
         """Advance ``k`` fused ticks with one super-chunk pass per operator.
 
@@ -489,6 +491,7 @@ class Engine:
         order; callers must pick ``k`` via :meth:`_fusible_ticks` so no
         interior tick carries a control or snapshot event.
         """
+        obs.count("engine.super_ticks")
         t0 = self.tick
         # Name the window for the device plane's chain fusion: a chain
         # head advances its followers inside its own dispatch and marks
@@ -521,13 +524,14 @@ class Engine:
                 continue
             ups = self.upstreams.get(op.name, [])
             if ups and all(self._producer_done(u) for u in ups) and op.queues_empty():
-                dev = op.device
-                if dev is not None and dev.ctrl is not None:
-                    dev.ctrl.retire()
-                outs = op.on_end()
-                if outs and op.out_edge is not None:
-                    op.out_edge.send(outs[0] if len(outs) == 1
-                                     else concat(outs))
+                with obs.span("engine.end"):
+                    dev = op.device
+                    if dev is not None and dev.ctrl is not None:
+                        dev.ctrl.retire()
+                    outs = op.on_end()
+                    if outs and op.out_edge is not None:
+                        op.out_edge.send(outs[0] if len(outs) == 1
+                                         else concat(outs))
         # 4 + 5. controllers and sink snapshot, through every covered tick
         # (interior ticks are no-ops when k came from _fusible_ticks).
         # The window end is a control boundary: drain device-resident

@@ -37,6 +37,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs
 from ..core.state_migration import OperatorTraits
 from ..core.types import StateMutability, TransferMode
 from .state import AggStore, ScopeRows, segment_starts
@@ -645,11 +646,12 @@ class Sink(Operator):
         # END snapshot in `on_end` still fires); the modulo would raise
         # on either degenerate value.
         if self.snapshot_every and tick % self.snapshot_every == 0:
-            if self.device is not None:
-                # The boundary readback: the result columns leave the
-                # device only on the snapshot grid.
-                self.device.sync_sink_counts()
-            self.series.append((tick, self.counts.copy()))
+            with obs.span("sink.snapshot"):
+                if self.device is not None:
+                    # The boundary readback: the result columns leave the
+                    # device only on the snapshot grid.
+                    self.device.sync_sink_counts()
+                self.series.append((tick, self.counts.copy()))
 
     def on_end(self):
         self.finished = True
