@@ -1004,6 +1004,12 @@ def _make_ctrl_step(kind: str):
     reduction goes through the canonical sequential order
     (:func:`repro.core.estimator.seq_sum` / ``kernels.ref.seq_sum_vec``)
     so decisions are bit-identical to :class:`ReshapeController`.
+
+    A round's work follows its live slots, not ``W``: the mitigation
+    advance visits only active mitigations, the helper assignment only
+    skewed workers, and each visit builds only the rewrite it applies.
+    The step returns ``(state, drained arrivals, visits)``, where
+    ``visits`` counts the slots visited over every round of the window.
     """
     import jax
     jnp = _jnp()
@@ -1055,8 +1061,11 @@ def _make_ctrl_step(kind: str):
             new_w = jnp.where(owned[:, None], row[None, :], c["weights"])
             return new_w, jnp.any(owned)
 
+        def keep_weights(c, s, h):
+            return c["weights"], jnp.bool_(False)
+
         def round_fn(st):
-            c, arr = st
+            c, arr, visits = st
             # ---- tracker.update (one metric round) ---------------------
             total = kref.seq_sum_vec(arr)
             has = total > 0
@@ -1073,20 +1082,25 @@ def _make_ctrl_step(kind: str):
             arr = jnp.zeros_like(arr)   # the adapter drains every round
 
             # ---- _advance_mitigations (insertion order == seq order) ---
-            def adv_body(_, st):
-                c, processed = st
-                seqs = jnp.where(c["mit_active"] & ~processed,
-                                 c["mit_seq"], BIG)
-                s = jnp.argmin(seqs)
-                have = seqs[s] < BIG
+            # One iteration per live slot: the loop runs while an active
+            # slot is unprocessed (``retire`` clears only the slot just
+            # processed), so a dead slot costs nothing.
+            def adv_cond(st):
+                c, processed, _ = st
+                return jnp.any(c["mit_active"] & ~processed)
+
+            def adv_body(st):
+                c, processed, visits = st
+                s = jnp.argmin(jnp.where(c["mit_active"] & ~processed,
+                                         c["mit_seq"], BIG))
                 h = c["mit_helper"][s]
                 phase = c["mit_phase"][s]
                 q_s = phi[s]
                 q_h = phi[h]
                 top = jnp.maximum(jnp.maximum(q_s, q_h), 1.0)
-                p1_to_p2 = (have & (phase == PH1)
+                p1_to_p2 = ((phase == PH1)
                             & (q_h >= q_s - cs.catchup_tolerance * top))
-                in_p2 = have & (phase == PH2)
+                in_p2 = phase == PH2
                 s_ahead = (q_s >= cs.eta) & (q_s - q_h >= c["tau"])
                 h_ahead = (q_h >= cs.eta) & (q_h - q_s >= c["tau"])
                 calm = in_p2 & ~(s_ahead | h_ahead)
@@ -1115,11 +1129,13 @@ def _make_ctrl_step(kind: str):
                 if not cs.enable_phase1:
                     start_p2 = start_p2 | start_p1
                     start_p1 = jnp.zeros_like(start_p1)
-                w1, ch1 = apply_phase1(c, s, h)
-                w2, ch2 = apply_phase2(c, s, h)   # post-reset shares
-                new_w = jnp.where(start_p1, w1,
-                                  jnp.where(start_p2, w2, c["weights"]))
-                bumped = (start_p1 & ch1) | (start_p2 & ch2)
+                # Build only the rewrite that fires (phase 2 reads the
+                # post-reset shares).
+                branch = jnp.where(start_p1, 1, jnp.where(start_p2, 2, 0))
+                new_w, changed = jax.lax.switch(
+                    branch, (keep_weights, apply_phase1, apply_phase2),
+                    c, s, h)
+                bumped = (branch > 0) & changed
                 c = dict(
                     c,
                     weights=new_w,
@@ -1135,11 +1151,10 @@ def _make_ctrl_step(kind: str):
                     mit_active=c["mit_active"].at[s].set(
                         c["mit_active"][s] & ~retire),
                 )
-                processed = processed.at[s].set(processed[s] | have)
-                return c, processed
+                return c, processed.at[s].set(True), visits + 1
 
-            c, _ = jax.lax.fori_loop(0, W, adv_body,
-                                     (c, jnp.zeros(W, bool)))
+            c, _, visits = jax.lax.while_loop(
+                adv_cond, adv_body, (c, jnp.zeros(W, bool), visits))
 
             # ---- _detect ----------------------------------------------
             helper_busy = (jnp.zeros(W, i32).at[c["mit_helper"]]
@@ -1171,16 +1186,20 @@ def _make_ctrl_step(kind: str):
             m2 = jnp.min(jnp.where(free & (idx != i1), phi, jnp.inf))
             min_excl = jnp.where(idx == i1, m2, m1)
             skewed = free & (phi >= cs.eta) & (phi - min_excl >= detect_tau)
-            shares = predicted_shares(c)
+            shares = jax.lax.cond(jnp.any(skewed), predicted_shares,
+                                  lambda c: jnp.zeros(W, c["obs"].dtype), c)
             L = tuples_left
 
-            def asg_body(_, st):
-                c, taken, processed = st
-                mask = skewed & ~processed
-                s = jnp.argmax(jnp.where(mask, phi, -jnp.inf))
-                have = jnp.any(mask)
+            # One iteration per skewed worker, hottest first.
+            def asg_cond(st):
+                _, _, processed, _ = st
+                return jnp.any(skewed & ~processed)
+
+            def asg_body(st):
+                c, taken, processed, visits = st
+                s = jnp.argmax(jnp.where(skewed & ~processed, phi, -jnp.inf))
                 cands = (free & ~taken & (phi[s] - phi >= detect_tau)
-                         & (idx != s) & have)
+                         & (idx != s))
                 ncand = jnp.sum(cands.astype(i32))
                 # choose_helpers, max_helpers=1: lexicographic min by
                 # (f_hat, phi, index) — the host's stable double sort
@@ -1194,20 +1213,18 @@ def _make_ctrl_step(kind: str):
                 lr_max = (f_s - (f_s + f_h) / 2.0) * L
                 future = jnp.maximum(L, 0.0) * f_s    # M = 0 (inf rate)
                 chi = jnp.minimum(lr_max, future)
-                accept = have & (ncand > 0) & (chi >= -1e-12)
+                accept = (ncand > 0) & (chi >= -1e-12)
                 # all of s's candidates become taken (host assign_helpers)
-                taken = taken | jnp.where(ncand > 0, cands,
-                                          jnp.zeros_like(cands))
-                processed = processed.at[s].set(processed[s] | have)
+                taken = taken | cands
                 if cs.enable_phase1:
-                    w_new, changed = apply_phase1(c, s, h)
-                    ph = i32(PH1)
+                    apply, ph = apply_phase1, i32(PH1)
                 else:
-                    w_new, changed = apply_phase2(c, s, h)
-                    ph = i32(PH2)
+                    apply, ph = apply_phase2, i32(PH2)
+                w_new, changed = jax.lax.cond(accept, apply, keep_weights,
+                                              c, s, h)
                 c = dict(
                     c,
-                    weights=jnp.where(accept, w_new, c["weights"]),
+                    weights=w_new,
                     epoch=c["epoch"] + (accept & changed).astype(i32),
                     mit_active=c["mit_active"].at[s].set(
                         c["mit_active"][s] | accept),
@@ -1222,12 +1239,13 @@ def _make_ctrl_step(kind: str):
                         jnp.where(accept, c["seq_next"], c["mit_seq"][s])),
                     seq_next=c["seq_next"] + accept.astype(i32),
                 )
-                return c, taken, processed
+                return c, taken, processed.at[s].set(True), visits + 1
 
             taken0 = busy | skewed      # skewed workers can't help
-            c, _, _ = jax.lax.fori_loop(0, W, asg_body,
-                                        (c, taken0, jnp.zeros(W, bool)))
-            return c, arr
+            c, _, _, visits = jax.lax.while_loop(
+                asg_cond, asg_body,
+                (c, taken0, jnp.zeros(W, bool), visits))
+            return c, arr, visits
 
         # Owner-attributed arrivals for this window (integer adds:
         # order-independent, exact) + one observation-log entry so the
@@ -1247,14 +1265,15 @@ def _make_ctrl_step(kind: str):
                                      cs.metric_period) == 0))
             return jax.lax.cond(fire, round_fn, lambda st: st, st)
 
-        c, _ = jax.lax.fori_loop(0, cs.KMAX, tick_body, (c, arr0))
+        c, _, visits = jax.lax.fori_loop(0, cs.KMAX, tick_body,
+                                         (c, arr0, jnp.int32(0)))
 
         def rebuild(c):
             cdf, primary, is_split = kref.routing_consts(c["weights"])
             return dict(c, cdf=cdf, primary=primary, is_split=is_split)
 
         c = jax.lax.cond(c["epoch"] != epoch0, rebuild, lambda c: c, c)
-        return c, jnp.zeros_like(arrived)
+        return c, jnp.zeros_like(arrived), visits
 
     return ctrl_step
 
@@ -1523,11 +1542,12 @@ class DeviceController:
             # The step on the CPU backend, through the epoch read that
             # waits for it (a wait on the host, not a chip readback).
             with obs.span("ctrl.cpu_step"):
-                c, drained = step(self.spec, self.cstate, arrived,
-                                  jax.device_put(np.asarray(
-                                      rt.workloads(), np.float64), cpu),
-                                  np.int64(t0), np.int64(k),
-                                  np.float64(left), np.float64(rate))
+                c, drained, visits = step(
+                    self.spec, self.cstate, arrived,
+                    jax.device_put(np.asarray(rt.workloads(), np.float64),
+                                   cpu),
+                    np.int64(t0), np.int64(k),
+                    np.float64(left), np.float64(rate))
                 self.cstate = c
                 dev = _data_device()
                 if rt.state is not None:
@@ -1536,6 +1556,9 @@ class DeviceController:
                     dict(cdf=c["cdf"], primary=c["primary"],
                          is_split=c["is_split"], owner=c["owner"]), dev)
                 self.epoch_host = int(np.asarray(c["epoch"]))
+            # CPU-backend scalars, not chip readbacks.
+            obs.count("ctrl.rounds", len(fired))
+            obs.count("ctrl.slot_visits", int(np.asarray(visits)))
         self.meta.append((t0, k, left, rate))
         host.rounds_on_device += len(fired)
 

@@ -33,11 +33,23 @@ def _skewed_stream(n, num_keys, seed=0, hot_frac=0.4):
     return keys, rng.uniform(0.0, 10.0, n)
 
 
+def _ranges_stream(n, num_keys, seed=0):
+    """W3's shape: every key live, the hottest ~2.3x the coldest, in
+    random order, so about half the workers run hot at once."""
+    rng = np.random.default_rng(seed)
+    w = 1.0 + 1.3 * rng.random(num_keys)
+    keys = rng.choice(num_keys, n, p=w / w.sum()).astype(np.int64)
+    return keys, rng.uniform(0.0, 10.0, n)
+
+
 def _monitored(backend=None, *, n=3000, num_keys=24, num_workers=4, chunk=8,
                batch_ticks=4, hot_frac=0.4, seed=0, metric_period=1,
-               cfg=None, snapshot_every=1, **engine_kw):
-    """Source -> GroupByAgg (monitored, SCATTERED-eligible) -> Sink."""
-    keys, vals = _skewed_stream(n, num_keys, seed, hot_frac)
+               cfg=None, snapshot_every=1, ranges=False, **engine_kw):
+    """Source -> GroupByAgg (monitored, SCATTERED-eligible) -> Sink; the
+    stream is :func:`_skewed_stream`, or :func:`_ranges_stream` with
+    ``ranges``."""
+    keys, vals = (_ranges_stream(n, num_keys, seed) if ranges
+                  else _skewed_stream(n, num_keys, seed, hot_frac))
     eng = Engine(partition_backend=backend, batch_ticks=batch_ticks,
                  **engine_kw)
     src = eng.add_source(Source("src", keys, vals, num_workers * chunk))
@@ -82,6 +94,25 @@ def _assert_same_decisions(a_ctrl, b_ctrl):
     assert _decisions(a_ctrl) == _decisions(b_ctrl)
 
 
+def _assert_armed_matches_host(a, b):
+    """Decisions, schedule, series, counts and routing of a host-stepped
+    run ``a`` equal those of an armed run ``b``, and ``b``'s drains never
+    found its in-dispatch decisions off the host twin's."""
+    assert not [i for i in b[0].incidents if i.kind == "ctrl-mismatch"]
+    _assert_same_decisions(a[3], b[3])
+    assert a[0].tick == b[0].tick
+    assert _series_equal(a[1].series, b[1].series)
+    np.testing.assert_array_equal(a[1].counts, b[1].counts)
+    for ea, eb in zip(a[0].edges, b[0].edges):
+        np.testing.assert_array_equal(ea.sent_per_worker,
+                                      eb.sent_per_worker)
+        eb.routing.sync_counters()
+        np.testing.assert_array_equal(ea.routing._count,
+                                      eb.routing._count)
+        np.testing.assert_array_equal(ea.routing.weights,
+                                      eb.routing.weights)
+
+
 class TestBitIdentity:
     @settings(max_examples=12, deadline=None)
     @given(st.integers(min_value=0, max_value=10_000),
@@ -104,18 +135,32 @@ class TestBitIdentity:
         dev = b[0].controllers[0].op.device
         assert dev is not None and dev.ctrl is not None and dev.ctrl.active
         _drive(b[0], k)
-        _assert_same_decisions(a[3], b[3])
-        assert a[0].tick == b[0].tick
-        assert _series_equal(a[1].series, b[1].series)
-        np.testing.assert_array_equal(a[1].counts, b[1].counts)
-        for ea, eb in zip(a[0].edges, b[0].edges):
-            np.testing.assert_array_equal(ea.sent_per_worker,
-                                          eb.sent_per_worker)
-            eb.routing.sync_counters()
-            np.testing.assert_array_equal(ea.routing._count,
-                                          eb.routing._count)
-            np.testing.assert_array_equal(ea.routing.weights,
-                                          eb.routing.weights)
+        _assert_armed_matches_host(a, b)
+
+    @pytest.mark.parametrize("seed, k", [(0, 4), (0, 8), (3, 4), (3, 8)])
+    def test_decisions_match_host_controller_with_every_worker_paired(
+            self, seed, k):
+        """The W3 regime: 20 workers, about half of them hot at once, so
+        the controller pairs every worker (10 live mitigations, an empty
+        skewed set) and then re-fires phase 2 round after round.  The
+        armed step, which visits only live slots, still decides bit for
+        bit as the host controller does."""
+        W = 20
+        kw = dict(n=4000, num_keys=40, num_workers=W, chunk=4, seed=seed,
+                  batch_ticks=k, ranges=True, snapshot_every=1,
+                  cfg=ReshapeConfig(eta=10.0, tau=10.0))
+        a = _monitored("pallas", device_executor="jit",
+                       device_controller=False, **kw)
+        paired = 0
+        while not a[0].done():
+            a[0].run_super_tick(k)
+            paired = max(paired, len(a[3].mitigations))
+        assert paired == W // 2, "the stream must pair every worker"
+        b = _monitored("pallas", device_executor="jit",
+                       device_controller=True, **kw)
+        assert b[0].controllers[0].op.device.ctrl.active
+        _drive(b[0], k)
+        _assert_armed_matches_host(a, b)
 
     @settings(max_examples=6, deadline=None)
     @given(st.integers(min_value=1, max_value=12),
@@ -257,6 +302,36 @@ class TestLifecycle:
         host[0].run()
         assert armed[0].super_ticks < host[0].super_ticks
         np.testing.assert_array_equal(host[1].counts, armed[1].counts)
+
+    def test_slot_visit_counters(self):
+        """The armed step counts its metric rounds (``ctrl.rounds``, the
+        rounds the dispatches ran) and the slots it visited in them
+        (``ctrl.slot_visits``, at most the 2·W of a visit to every
+        slot); a run that never mitigates nor detects skew visits none,
+        and a host-stepped run keeps neither counter."""
+        from repro import obs
+
+        def grown(**kw):
+            before = obs.counters()
+            run = _monitored("pallas", device_executor="jit", n=2000,
+                             batch_ticks=8, **kw)
+            _drive(run[0], 8)
+            after = obs.counters()
+            return run, {k: after.get(k, 0) - before.get(k, 0)
+                         for k in ("ctrl.rounds", "ctrl.slot_visits")}
+
+        armed, c = grown(device_controller=True)
+        W = armed[2].num_workers
+        assert c["ctrl.rounds"] == armed[3].rounds_on_device > 0
+        assert 0 < c["ctrl.slot_visits"] <= 2 * W * c["ctrl.rounds"]
+        calm, c = grown(device_controller=True,
+                        cfg=ReshapeConfig(eta=1e12, tau=1e12))
+        assert not calm[3].events
+        assert c["ctrl.rounds"] == calm[3].rounds_on_device > 0
+        assert c["ctrl.slot_visits"] == 0
+        host, c = grown(device_controller=False)
+        assert host[3].events and host[3].rounds_on_device == 0
+        assert c == {"ctrl.rounds": 0, "ctrl.slot_visits": 0}
 
     def test_metric_messages_accounting(self):
         """Armed: in-dispatch rounds cost no host traffic; only boundary
