@@ -9,6 +9,8 @@ paper's tuple counts, preserving the *ratios* every experiment depends on:
              is the small co-resident key on CA's worker at 48 cores.
   dsb      — sales fact table keyed by date (moderate skew), item (high
              skew), customer (mild skew): Zipf-like with different s.
+             DSB's SF 1 has 18,000 items (TPC-DS spec 3.2, the
+             benchmark's ``dsb-storesales-groupby``); 128 is the CI scale.
   tpch     — Orders totalprice values, log-normal-ish (Fig. 15b), range
              partitioned for the Sort workflow.
   synthetic— W4's two-phase distribution change: 80% key 0 for the first

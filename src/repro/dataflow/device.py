@@ -295,8 +295,10 @@ MAX_SERVICE_RATE = 1 << 20
 #: routing works on blocks of lanes whose [lanes, K] or [lanes, W]
 #: temporaries hold at most this many cells; split counters count with
 #: one-hot prefix sums while K is at most ``ONEHOT_MAX_KEYS`` and sort
-#: beyond.  A TPU compiles a 2**20-lane sort in about 90 s, the blocked
-#: prefix sums in about 4 s.
+#: the whole window beyond.  A TPU compiles a 2**20-lane sort in about
+#: 90 s, the blocked prefix sums in about 4 s; so an armed edge traces
+#: the split-aware step up front only up to ``ONEHOT_MAX_KEYS`` keys, and
+#: beyond it only while its uploaded consts split a key.
 ONEHOT_BLOCK_CELLS = 1 << 21
 ONEHOT_MAX_KEYS = 2048
 
@@ -1556,6 +1558,9 @@ class DeviceController:
                     dict(cdf=c["cdf"], primary=c["primary"],
                          is_split=c["is_split"], owner=c["owner"]), dev)
                 self.epoch_host = int(np.asarray(c["epoch"]))
+                # CPU-backend output, not a chip readback
+                rt._n_split = int(np.count_nonzero(np.asarray(
+                    c["is_split"])))
             # CPU-backend scalars, not chip readbacks.
             obs.count("ctrl.rounds", len(fired))
             obs.count("ctrl.slot_visits", int(np.asarray(visits)))
@@ -1636,7 +1641,7 @@ class DeviceController:
                  for k in ("cdf", "primary", "is_split", "owner")},
                 _data_device())
         rt._consts_version = table.version
-        rt._consts_split = bool(table._any_split)
+        rt._n_split = int(np.count_nonzero(table._is_split))
         self.epoch_synced = self.epoch_host
 
     # ---- retry/backoff against injected dispatch faults ----------------
@@ -1754,7 +1759,7 @@ class DeviceOpRuntime:
         self._pull = self._pull_counters    # stable identity (ownership)
         self._host_fresh = False   # host copies match device state
         self._reload_pending = False   # host mutated: reload pre-dispatch
-        self._consts_split = False  # any_split of the uploaded consts
+        self._n_split = 0  # split keys of the uploaded consts
         #: placement (partition + scatter) executions, for the bench's
         #: placements-per-super-tick provenance row; chain fusion makes
         #: this 0 on every non-head edge of a fused chain.
@@ -1786,8 +1791,10 @@ class DeviceOpRuntime:
             # An in-dispatch rewrite may split keys mid-window; trace the
             # split-aware step up front.  On one-hot tables the saturated
             # cdf routes every draw to the primary, so this is bit-neutral
-            # while no split exists.
-            any_split = True
+            # while no split exists.  Past ONEHOT_MAX_KEYS that step sorts
+            # the whole window: follow the uploaded consts (the device's
+            # table, ahead of the host's between drains) instead.
+            any_split = self.K <= ONEHOT_MAX_KEYS or bool(self._n_split)
         return StepSpec(kind=self.kind, W=self.W, K=self.K, cap=self.cap,
                         B=self.B, any_split=bool(any_split),
                         may_scatter=bool(self.op.may_scatter),
@@ -1796,6 +1803,23 @@ class DeviceOpRuntime:
                                          is not None),
                         use_kernel=self.use_kernel, fn=self._fn,
                         M=self.M, rcap=self.rcap)
+
+    def _count_ingest(self, chunk: Optional[DeviceChunk]) -> None:
+        """Host mirrors of a dispatch that ingests ``chunk`` (None: it
+        only pops): a keyed fold's input lanes and host-known live lanes;
+        and, while the uploaded consts split keys, the dispatch, their
+        split-key count and the lanes its split-key rank pass covers (the
+        whole chunk)."""
+        if chunk is None:
+            return
+        n = int(chunk.keys.shape[0])
+        if self.kind == "fold":
+            obs.count("device.fold_lanes", n)
+            obs.count("device.fold_live", chunk.n_live)
+        if self._n_split:
+            obs.count("device.split_dispatches")
+            obs.count("device.split_keys", self._n_split)
+            obs.count("device.rank_lanes", n)
 
     def backlog_total(self) -> int:
         return int(self.lens.sum()) + self.staged_live
@@ -2444,7 +2468,7 @@ class DeviceOpRuntime:
                     is_split=jnp.asarray(rt._is_split, bool),
                     owner=jnp.asarray(rt.owner.copy(), jnp.int64))
             self._consts_version = rt.version
-            self._consts_split = bool(rt._any_split)
+            self._n_split = int(np.count_nonzero(rt._is_split))
 
     def _pull_counters(self) -> np.ndarray:
         return _readback(self.state["count"], "counters")
@@ -2494,7 +2518,7 @@ class DeviceOpRuntime:
             return
         chunks = list(self.staged)
         self._dispatch(_step_for(self.kind),
-                       self._spec(any_split=self._consts_split), chunks, 0)
+                       self._spec(any_split=bool(self._n_split)), chunks, 0)
         self.staged, self.staged_live = [], 0
 
     def tick(self, budget: int) -> List:
@@ -2749,6 +2773,7 @@ class DeviceOpRuntime:
             if len(chunks) == 1:
                 ch = chunks[0]
                 dc = (ch.keys, ch.vals, ch.valid)
+                self._count_ingest(ch)
             elif chunks:
                 # Rare multi-chunk stage (END flushes): ingest per-edge
                 # first (budget 0 pops nothing), then chain pop-only —
@@ -2795,7 +2820,12 @@ class DeviceOpRuntime:
                 r._placed_token = tok
             else:
                 r._placed_token = None
+        handed = None      # (lanes, live) the previous stage handed on
         for r, (hist, take, emitted) in zip(members, metrics):
+            if r.kind == "fold" and handed is not None:
+                obs.count("device.fold_lanes", handed[0])
+                obs.count("device.fold_live", handed[1])
+            handed = None
             hist = _readback(hist, "hist")
             r.edge.exchange.account(hist)
             r.received += hist
@@ -2813,6 +2843,7 @@ class DeviceOpRuntime:
                 em = _readback(emitted, "emitted")
                 for w, worker in enumerate(r.op.workers):
                     worker.stats.emitted_total += int(em[w])
+                handed = (r.W * r._emit_bound(r.B), int(em.sum()))
         for r in members[1:]:
             r._chain_serial = eng._super_serial
         if dc is not None:
@@ -2878,6 +2909,7 @@ class DeviceOpRuntime:
             for ch, b in seq:
                 dc = (None if ch is None
                       else (ch.keys, ch.vals, ch.valid))
+                self._count_ingest(ch)
                 with obs.span("device.dispatch"):
                     res = step(spec, self.consts, self.state, dc,
                                np.int64(b))

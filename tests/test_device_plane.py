@@ -12,6 +12,8 @@ streams.  Off-TPU the *default* executor is the host twin (same
 canonical rule through the fused numpy exchange); that default is pinned
 here too.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -145,6 +147,91 @@ class TestJitPlaneEquivalence:
             want = run()                            # the sort path
         for g, w in zip(got, want):
             np.testing.assert_array_equal(g, w)
+
+    @pytest.mark.parametrize("live_share", [0.0, 0.01, 1.0],
+                             ids=["none-live", "1pct-live", "all-live"])
+    @pytest.mark.parametrize("n_split", [0, 1, 2250])
+    @pytest.mark.parametrize("K", [2048, 2049, 18000])
+    def test_armed_routing_past_onehot_follows_the_consts(
+            self, K, n_split, live_share):
+        """An armed edge traces the split-aware step up front while K is
+        at most ONEHOT_MAX_KEYS; past it, where that step sorts the whole
+        window, only while its uploaded consts split a key.  Either way
+        its destinations, ranks, histogram and counts equal the
+        split-aware step's and ``RoutingTable.route_chunk`` (the
+        engine's rule: ``advance_counters`` and the inverse CDF) lane for
+        lane, and the dispatch's counters
+        move as stated.  At K <= 2,250 the widest case splits every
+        key."""
+        import types
+
+        import jax
+
+        from repro import obs
+        from repro.core.partitioner import RoutingTable
+        from repro.dataflow import device as dev
+        W, n = 40, 5000
+        n_split = min(n_split, K)
+        rng = np.random.default_rng(K + n_split)
+        split = rng.choice(K, n_split, replace=False)
+        hot = split[:4] if n_split else np.arange(4)
+        keys = np.where(rng.random(n) < 0.5, rng.choice(hot, n),
+                        rng.integers(0, K, n))
+        valid = rng.random(n) < live_share
+        table = RoutingTable(K, W)
+        for k in split:
+            table.split_key(int(k), [k % W, (k + 1) % W], [0.5, 0.5])
+        table._refresh_derived()
+        assert int(table._is_split.sum()) == n_split
+        count = rng.integers(0, 50, K)
+        table._count[:] = count
+
+        rt = dev.DeviceOpRuntime.__new__(dev.DeviceOpRuntime)
+        rt.routing, rt.kind, rt.W, rt.K, rt.cap, rt.B = (table, "fold", W,
+                                                         K, 256, 8)
+        rt.op = types.SimpleNamespace(may_scatter=True,
+                                      track_key_stats=False,
+                                      arrived_by_key=None)
+        rt.ctrl = types.SimpleNamespace(active=True)
+        rt.use_kernel, rt._fn, rt.M, rt.rcap = False, None, 1, 0
+        rt._n_split = n_split
+        spec = rt._spec()
+        assert spec.any_split == (K <= dev.ONEHOT_MAX_KEYS or n_split > 0)
+
+        with jax.enable_x64(True):
+            chunk = dev.DeviceChunk(jax.numpy.asarray(keys), None,
+                                    jax.numpy.asarray(valid),
+                                    int(valid.sum()))
+        before = dict(obs.counters())
+        rt._count_ingest(chunk)
+        moved = {k: v - before.get(k, 0) for k, v in obs.counters().items()
+                 if k.startswith("device.") and v != before.get(k, 0)}
+        want = {"device.fold_lanes": n, "device.fold_live": chunk.n_live}
+        if n_split:
+            want.update({"device.split_dispatches": 1,
+                         "device.split_keys": n_split,
+                         "device.rank_lanes": n})
+        assert moved == {k: v for k, v in want.items() if v}
+
+        consts = {"cdf": table.cdf32, "primary": table._primary,
+                  "is_split": table._is_split}
+
+        def run(s):
+            f = jax.jit(lambda c, cnt, k, v: dev._advance_and_route(
+                s, c, cnt, k, v))
+            return [np.asarray(x) for x in f(consts, count, keys, valid)]
+
+        with jax.enable_x64(True):
+            got = run(spec)
+            aware = run(dataclasses.replace(spec, any_split=True))
+        for g, w in zip(got, aware):
+            np.testing.assert_array_equal(g, w)
+        dest, _, hist, new_count = got
+        np.testing.assert_array_equal(dest[valid],
+                                      table.route_chunk(keys[valid]))
+        np.testing.assert_array_equal(hist, np.bincount(dest[valid],
+                                                        minlength=W))
+        np.testing.assert_array_equal(new_count, table._count)
 
     def test_groupby_state_identical(self):
         a = _pipeline("numpy", predicate=_half_pass)
@@ -361,6 +448,71 @@ def _mirrors_equal(a_eng, b_eng):
         for wa, wb in zip(oa.workers, ob.workers):
             assert wa.stats.processed_total == wb.stats.processed_total
             assert wa.stats.emitted_total == wb.stats.emitted_total
+
+
+class TestDsbItemGroupBy:
+    """The benchmark's DSB store_sales group-by (18,000 items, Zipf 2.0,
+    40 workers) at ~60k rows: the fused Filter -> GroupBy chain with the
+    group-by's controller armed, on the jit plane, against the numpy
+    plane and the configuration's plain reference."""
+
+    def test_chain_armed_matches_numpy_plane_and_reference(self,
+                                                           monkeypatch):
+        import os
+
+        from repro.analysis import sanitize
+        from repro.dataflow import device as dev
+        monkeypatch.syspath_prepend(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+        from bench import harness
+        cfg, mod = harness.load_config("dsb-storesales-groupby")
+        cfg.update(scale_factor=60_000 / cfg["rows_per_scale_factor"],
+                   service_rate=8, filter_rate=8 * cfg["num_workers"],
+                   batch_ticks=8, snapshot_every=8)
+        d = mod.make_data(cfg, {}, 2**31 + 11)
+        W, K = cfg["num_workers"], cfg["num_items"]
+
+        host = Engine(partition_backend="numpy", batch_ticks=8)
+        src = host.add_source(Source("sales", d["keys"], d["vals"], W * 8))
+        filt = host.add_op(Filter("filter", W, W * 8, predicate=mod._keep))
+        grp = host.add_op(GroupByAgg("groupby_item", W, 8))
+        sink = host.add_op(Sink("out", K, snapshot_every=8))
+        for a, b in ((src, filt), (filt, grp), (grp, sink)):
+            host.connect(a, b, K)
+        host.attach_controller(grp, ReshapeConfig(**cfg["reshape"]))
+        while not host.done():          # the armed plane's window grid
+            host.run_super_tick(8)
+
+        splits = []             # the split keys each ingest routes with
+        count_ingest = dev.DeviceOpRuntime._count_ingest
+
+        def spy(rt, chunk):
+            if chunk is not None:
+                splits.append(rt._n_split)
+            return count_ingest(rt, chunk)
+
+        monkeypatch.setattr(dev.DeviceOpRuntime, "_count_ingest", spy)
+        traced = dict(sanitize.trace_counts())
+        eng, dgrp, dsink = mod.build(cfg, d, executor="jit")
+        while not eng.done():
+            eng.run_super_tick(8)
+        assert eng.tick == host.tick
+        assert all(e.device_plane == "jit" for e in eng.edges)
+        assert any(k[0] == "chain" and n > traced.get(k, 0)
+                   for k, n in sanitize.trace_counts().items())
+        assert max(splits) > dev.ONEHOT_MAX_KEYS
+        assert _series_equal(sink.series, dsink.series)
+        np.testing.assert_array_equal(sink.counts, dsink.counts)
+        # the fold adds lane by lane, the numpy plane a chunk's bincount at
+        # once: the float64 sums agree to rounding, not bit for bit
+        np.testing.assert_allclose(dsink.sums, sink.sums, rtol=1e-12)
+        for wa, wb in zip(grp.workers, dgrp.workers):
+            np.testing.assert_array_equal(wa.state.counts, wb.state.counts)
+        numbers = mod.compare(cfg, d, mod.reference(cfg, d),
+                              mod.outputs(eng, dgrp, dsink))
+        assert numbers["count_mismatch"] == 0
+        assert numbers["series_violations"] == 0
+        assert numbers["sums_rel_err"] <= cfg["limits"]["sums_rel_err"]
 
 
 class TestChainFusion:

@@ -128,7 +128,7 @@ def test_one_edge_groupby_pays_two_readbacks_per_dispatch():
     pushed and popped counts (``hist``, ``take``) and nothing else; the
     blocking GroupBy sends the sink nothing before END.  The rings drain
     every tick, so the records resident at the pops are the super-tick's
-    emission."""
+    emission; the fold ingests that emission in one padded chunk."""
     eng, grp = _one_edge_groupby()
     eng.run_super_tick(4)                    # allocates the device state
     rt = grp.device
@@ -139,7 +139,9 @@ def test_one_edge_groupby_pays_two_readbacks_per_dispatch():
         assert rec.counters == {"engine.super_ticks": 1,
                                 "device.readbacks": 2,
                                 "device.ring_slots": rt.W * rt.cap,
-                                "device.ring_live": 4 * 100}
+                                "device.ring_live": 4 * 100,
+                                "device.fold_lanes": rt.NB,
+                                "device.fold_live": 4 * 100}
         assert [(s.name, s.args) for s in rec.spans] == [
             ("engine.super_tick", {}), ("device.dispatch", {}),
             ("device.readback", {"site": "hist"}),
