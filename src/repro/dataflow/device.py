@@ -28,6 +28,20 @@ the next stay on the device as padded, validity-masked
 :class:`DeviceChunk` buffers, so consecutive fused edges share one
 residency domain.
 
+Flat map pops
+-------------
+A Filter / Project stage maps lane by lane, so it pops its rings into
+one flat window (:func:`_pop_flat`) instead of the ``[W, B]``
+rectangle the other kinds pop: each ring's take, worker-major and in
+stream order within a worker — the rectangle's live lanes in its
+row-major order, less the padding between and after the rows.  The host
+sizes the window per dispatch from what it knows (resident ring lanes
+plus the lanes the dispatch brings in, at most ``W * budget``), rounded
+up to a power of two and never wider than ``W * B``, so a skewed or
+sparse window costs its live lanes, not ``W * B``.  Downstream steps see
+the same live lanes in the same order, so routing, ranks, ring contents
+and folds are unchanged.
+
 Row-state operators (HashJoin / Sort)
 -------------------------------------
 The full paper operator set runs on this plane, not just keyed folds:
@@ -78,10 +92,10 @@ routes the same key space through the same primaries
 (``RoutingTable.routing_token()`` equality; tokens exist only for
 one-hot tables, whose destinations are counter-independent), every
 surviving record's destination on edge B *is* the worker it already
-occupies.  So the map step hands its downstream stage a **pre-placed**
-``[W, B]`` block — row *w* belongs to ring *w* — and the downstream
-ingest (:func:`_push_placed`) is a rank-by-row-cumsum ring append: no
-partition, no inverse-CDF, no one-hot rank matrix.  The whole chain
+occupies.  So the map step hands its downstream stage **pre-placed**
+lanes, each tagged with the ring it belongs to, and the downstream
+ingest (:func:`_push_placed`) is a rank-by-segment-cumsum ring append:
+no partition, no inverse-CDF, no one-hot rank matrix.  The whole chain
 (map stages plus the final fold / sink / map tail) advances in **one**
 jitted dispatch per super-tick (:func:`_make_step_chain`, trace-cached
 on the tuple of per-stage :class:`StepSpec`\\ s), and per-super-tick
@@ -302,6 +316,9 @@ MAX_SERVICE_RATE = 1 << 20
 ONEHOT_BLOCK_CELLS = 1 << 21
 ONEHOT_MAX_KEYS = 2048
 
+#: the kinds that pop into a flat window (:func:`_pop_flat`)
+MAP_KINDS = ("filter", "project")
+
 #: probe-expand ceiling: the emit buffer is W * B * M lanes (M = the max
 #: per-tuple build-match fanout, so it always covers the worst case and
 #: carry-over never has to defer outputs past the host plane's tick);
@@ -492,6 +509,7 @@ class StepSpec:
     fn: Optional[Callable] = None   # Filter predicate / Project map
     M: int = 1                   # probe: max per-tuple match fanout
     rcap: int = 0                # rows: segment-store capacity (pow2)
+    P: int = 0                   # filter / project: flat pop width
 
 
 # --------------------------------------------------------------------- #
@@ -629,6 +647,54 @@ def _pop(spec: StepSpec, state, budget):
     return wk, wv, wmask, take, dict(state, head=state["head"] + take)
 
 
+def _pop_flat(spec: StepSpec, state, budget):
+    """Pop each ring's ``take = min(budget, len)`` into one flat window of
+    ``spec.P`` lanes: worker ``w`` owns lanes ``bounds[w] <= j <
+    bounds[w + 1]`` (``bounds`` the exclusive cumsum of ``take``), in
+    stream order, and lanes past ``bounds[W]`` are dead.  The host sizes
+    ``P`` to cover every take.  Returns the flat carry ``(keys, vals,
+    live, wid, bounds)``, ``take`` and the popped state."""
+    jnp = _jnp()
+    lens = state["tail"] - state["head"]
+    take = jnp.minimum(budget, lens)                       # [W]
+    bounds = jnp.concatenate([jnp.zeros((1,), lens.dtype),
+                              jnp.cumsum(take)])           # [W + 1]
+    start = bounds[:-1]
+    lane = jnp.arange(spec.P, dtype=lens.dtype)
+    # A marker at each worker's first lane, summed up to a lane, names the
+    # lane's worker; a worker that takes nothing shares the next one's
+    # start, and a start past the window is dropped.
+    marks = jnp.zeros((spec.P,), jnp.int32).at[start].add(1, mode="drop")
+    wid = jnp.cumsum(marks) - 1
+    slot = (state["head"][wid] + lane - start[wid]) % spec.cap
+    flat = wid.astype(lens.dtype) * spec.cap + slot
+    wk = state["rk"].reshape(-1)[flat]
+    wv = state["rv"].reshape(-1)[flat]
+    live = lane < bounds[-1]
+    return ((wk, wv, live, wid, bounds), take,
+            dict(state, head=state["head"] + take))
+
+
+def _rows_as_flat(ok, ov, keep):
+    """A ``[W, n]`` block (a probe's expansion) as a flat carry: row ``w``
+    is worker ``w``'s segment."""
+    jnp = _jnp()
+    W, n = keep.shape
+    wid = jnp.arange(W * n, dtype=jnp.int32) // n
+    bounds = jnp.arange(W + 1, dtype=jnp.int64) * n
+    return ok.reshape(-1), ov.reshape(-1), keep.reshape(-1), wid, bounds
+
+
+def _segment_counts(keep, bounds):
+    """``(before, counts)`` of a flat carry's kept lanes: ``before[j]``
+    kept lanes precede lane ``j`` (``[n + 1]``), ``counts[w]`` lie in
+    worker ``w``'s segment."""
+    jnp = _jnp()
+    before = jnp.concatenate([jnp.zeros((1,), bounds.dtype),
+                              jnp.cumsum(keep, dtype=bounds.dtype)])
+    return before, before[bounds[1:]] - before[bounds[:-1]]
+
+
 def _fold_stats(spec: StepSpec, state, keys, valid):
     if not spec.track_stats:
         return state
@@ -681,31 +747,32 @@ def _ingest_rows_kernel(spec: StepSpec, consts, state, chunk):
     return state, hist
 
 
-def _push_placed(spec: StepSpec, state, ok, ov, keep, hist):
-    """Ring-scatter a *pre-placed* ``[W, B]`` block: row ``w``'s live
-    lanes append to ring ``w`` in lane (stream) order.  This is the fused
+def _push_placed(spec: StepSpec, state, carry):
+    """Ring-scatter a *pre-placed* flat carry: worker ``w``'s kept lanes
+    append to ring ``w`` in lane (stream) order.  This is the fused
     chain's ingest — the records were placed by the upstream edge's
     partition, and routing-token equality proves edge B would place them
-    identically, so within-destination rank degenerates to a per-row
-    cumsum and no partition runs at all."""
+    identically, so within-destination rank degenerates to a segment
+    cumsum and no partition runs at all.  Returns the state and the
+    per-ring pushed counts."""
     jnp = _jnp()
-    dt = state["tail"].dtype
-    kin = keep.astype(dt)
-    rank = jnp.cumsum(kin, axis=1) - kin
-    pos = (state["tail"][:, None] + rank) % spec.cap
-    wid = jnp.arange(spec.W, dtype=dt)[:, None]
-    flat = jnp.where(keep, wid * spec.cap + pos,
-                     spec.W * spec.cap).reshape(-1)
+    ok, ov, keep, wid, bounds = carry
+    before, hist = _segment_counts(keep, bounds)
+    rank = before[:-1] - before[bounds[wid]]
+    pos = (state["tail"][wid] + rank) % spec.cap
+    flat = jnp.where(keep, wid.astype(pos.dtype) * spec.cap + pos,
+                     spec.W * spec.cap)
     rk = state["rk"].reshape(-1).at[flat].set(
-        ok.reshape(-1), mode="drop").reshape(spec.W, spec.cap)
+        ok, mode="drop").reshape(spec.W, spec.cap)
     rv = state["rv"].reshape(-1).at[flat].set(
-        ov.reshape(-1), mode="drop").reshape(spec.W, spec.cap)
-    return dict(state, rk=rk, rv=rv, tail=state["tail"] + hist)
+        ov, mode="drop").reshape(spec.W, spec.cap)
+    hist = hist.astype(state["tail"].dtype)
+    return dict(state, rk=rk, rv=rv, tail=state["tail"] + hist), hist
 
 
 def _map_stage(spec: StepSpec, wk, wv, wmask):
-    """Apply a Filter predicate / Project map to a popped ``[W, B]``
-    window of value bits; returns (out_keys, out_vals, keep)."""
+    """Apply a Filter predicate / Project map to popped lanes of value
+    bits; returns (out_keys, out_vals, keep)."""
     if spec.kind == "filter":
         keep = wmask & spec.fn(wk, _as_f64(wv)).astype(bool)
         ok, ov = wk, wv
@@ -827,14 +894,17 @@ def _make_step_map(kind: str):
             state, hist = _ingest(spec, consts, state, chunk)
         else:
             hist = jnp.zeros((spec.W,), state["tail"].dtype)
-        wk, wv, wmask, take, state = _pop(spec, state, budget)
         if spec.kind == "probe":
+            wk, wv, wmask, take, state = _pop(spec, state, budget)
             ok, ov, keep = _expand_stage(spec, state, wk, wv, wmask)
+            emitted = keep.sum(axis=1, dtype=take.dtype)
+            ok, ov, keep = ok.reshape(-1), ov.reshape(-1), keep.reshape(-1)
         else:
-            ok, ov, keep = _map_stage(spec, wk, wv, wmask)
-        out = (ok.reshape(-1), ov.reshape(-1), keep.reshape(-1))
-        emitted = keep.sum(axis=1, dtype=take.dtype)
-        return state, out, (hist, take, emitted)
+            (wk, wv, live, _, bounds), take, state = _pop_flat(
+                spec, state, budget)
+            ok, ov, keep = _map_stage(spec, wk, wv, live)
+            emitted = _segment_counts(keep, bounds)[1].astype(take.dtype)
+        return state, (ok, ov, keep), (hist, take, emitted)
 
     return step
 
@@ -842,8 +912,8 @@ def _make_step_map(kind: str):
 def _make_step_chain(kind: str):
     """One jitted dispatch advancing a whole fused chain: the head's
     ingest runs the chain's *single* partition + scatter; every later
-    stage receives its predecessor's pre-placed ``[W, B]`` survivors
-    (:func:`_push_placed` — no placement), pops its own budget, and
+    stage receives its predecessor's pre-placed survivors as a flat
+    carry (:func:`_push_placed` — no placement), pops its own budget, and
     maps / folds.  Per-stage ``(hist, take, emitted)`` metrics feed the
     same host mirrors the per-edge dispatches keep."""
     import jax
@@ -865,43 +935,43 @@ def _make_step_chain(kind: str):
                 else:
                     hist = jnp.zeros((spec.W,), st["tail"].dtype)
             else:
-                ok, ov, keep = carry
-                hist = keep.sum(axis=1, dtype=st["count"].dtype)
-                st = _fold_stats(spec, st, ok.reshape(-1), keep.reshape(-1))
+                ok, ov, keep, _, bounds = carry
+                st = _fold_stats(spec, st, ok, keep)
                 if spec.kind == "sink":
-                    kf = ok.reshape(-1)
-                    mf = keep.reshape(-1)
+                    hist = _segment_counts(keep, bounds)[1].astype(
+                        st["count"].dtype)
                     states[i] = dict(
                         st,
-                        counts=st["counts"].at[kf].add(
-                            mf.astype(st["counts"].dtype)),
-                        sums=st["sums"].at[kf].add(
-                            jnp.where(mf, _as_f64(ov).reshape(-1), 0.0)))
+                        counts=st["counts"].at[ok].add(
+                            keep.astype(st["counts"].dtype)),
+                        sums=st["sums"].at[ok].add(
+                            jnp.where(keep, _as_f64(ov), 0.0)))
                     metrics.append((hist, None, None))
                     carry = None
                     continue
-                st = _push_placed(spec, st, ok, ov, keep, hist)
-            wk, wv, wmask, take, st = _pop(spec, st, budgets[i])
-            if spec.kind in ("filter", "project", "probe"):
-                ok, ov, keep = (_expand_stage(spec, st, wk, wv, wmask)
-                                if spec.kind == "probe"
-                                else _map_stage(spec, wk, wv, wmask))
-                carry = (ok, ov, keep)
-                metrics.append((hist, take,
-                                keep.sum(axis=1, dtype=take.dtype)))
-            elif spec.kind == "rows":           # build / sort tail
-                st = _fold_rows(spec, consts, st, wk, wv, wmask, take)
-                metrics.append((hist, take, None))
-                carry = None
-            else:                               # fold tail
-                st = _fold_popped(spec, consts, st, wk, wv, wmask)
-                metrics.append((hist, take, None))
-                carry = None
+                st, hist = _push_placed(spec, st, carry)
+            carry = None
+            if spec.kind in MAP_KINDS:
+                (wk, wv, live, wid, bounds), take, st = _pop_flat(
+                    spec, st, budgets[i])
+                ok, ov, keep = _map_stage(spec, wk, wv, live)
+                carry = (ok, ov, keep, wid, bounds)
+                emitted = _segment_counts(keep, bounds)[1].astype(take.dtype)
+            else:
+                wk, wv, wmask, take, st = _pop(spec, st, budgets[i])
+                emitted = None
+                if spec.kind == "probe":
+                    ok, ov, keep = _expand_stage(spec, st, wk, wv, wmask)
+                    carry = _rows_as_flat(ok, ov, keep)
+                    emitted = keep.sum(axis=1, dtype=take.dtype)
+                elif spec.kind == "rows":       # build / sort tail
+                    st = _fold_rows(spec, consts, st, wk, wv, wmask, take)
+                else:                           # fold tail
+                    st = _fold_popped(spec, consts, st, wk, wv, wmask)
+            metrics.append((hist, take, emitted))
             states[i] = st
-        out = None
-        if carry is not None:                   # map tail emits downstream
-            ok, ov, keep = carry
-            out = (ok.reshape(-1), ov.reshape(-1), keep.reshape(-1))
+        # a map or probe tail emits its lanes downstream
+        out = None if carry is None else carry[:3]
         return tuple(states), out, tuple(metrics)
 
     return step
@@ -1782,7 +1852,9 @@ class DeviceOpRuntime:
         self._ctrl_refused: Optional[str] = None
 
     # ---- small helpers ------------------------------------------------ #
-    def _spec(self, any_split: Optional[bool] = None) -> StepSpec:
+    def _spec(self, any_split: Optional[bool] = None, P: int = 0) -> StepSpec:
+        """The step's static half; ``P`` is a map stage's flat pop width
+        (:meth:`_flat_width`)."""
         rt = self.routing
         rt._refresh_derived()
         if any_split is None:
@@ -1802,7 +1874,30 @@ class DeviceOpRuntime:
                                          and self.op.arrived_by_key
                                          is not None),
                         use_kernel=self.use_kernel, fn=self._fn,
-                        M=self.M, rcap=self.rcap)
+                        M=self.M, rcap=self.rcap, P=int(P))
+
+    def _flat_width(self, budget: int, incoming: int) -> int:
+        """A map stage's flat pop window for its next dispatch: it takes at
+        most ``budget`` a ring, and no more than the resident ring lanes
+        plus the ``incoming`` lanes the dispatch pushes first; rounded up
+        to a power of two (a few widths, so a few compiles) and never
+        wider than the ``[W, B]`` rectangle."""
+        resident = int((self.lens - self.spilled_lens).sum())
+        n = min(self.W * int(budget), resident + int(incoming))
+        return min(_pow2(n), self.W * self.B)
+
+    def _carry_width(self, spec: StepSpec) -> int:
+        """Lanes a map or probe stage hands downstream in one dispatch."""
+        if self.kind in MAP_KINDS:
+            return spec.P
+        return self.W * self._emit_bound(self.B)
+
+    def _count_map_pop(self, spec: StepSpec, take: np.ndarray) -> None:
+        """Host mirrors of a map stage's flat pop: its window and the lanes
+        it took."""
+        if self.kind in MAP_KINDS:
+            obs.count("device.map_pop_lanes", spec.P)
+            obs.count("device.map_pop_live", int(take.sum()))
 
     def _count_ingest(self, chunk: Optional[DeviceChunk]) -> None:
         """Host mirrors of a dispatch that ingests ``chunk`` (None: it
@@ -2767,20 +2862,27 @@ class DeviceOpRuntime:
                 # (whose M is final — its _prep already ran).
                 r._prep(b, incoming=members[i - 1]._emit_bound(
                     budgets[i - 1]) if i else 0)
-            spec0 = self._spec()
             chunks, self.staged, self.staged_live = self.staged, [], 0
             dc = None
+            incoming = 0
             if len(chunks) == 1:
                 ch = chunks[0]
                 dc = (ch.keys, ch.vals, ch.valid)
+                incoming = ch.n_live
                 self._count_ingest(ch)
             elif chunks:
                 # Rare multi-chunk stage (END flushes): ingest per-edge
                 # first (budget 0 pops nothing), then chain pop-only —
                 # bit-identical to the per-edge [(c,0)...(c,B)] sequence.
-                self._dispatch(_step_for(self.kind), spec0, chunks, 0)
+                self._dispatch(_step_for(self.kind), self._spec(), chunks, 0)
                 ingested = True
-            specs = (spec0,) + tuple(r._spec() for r in members[1:])
+            specs = []
+            for r, b in zip(members, budgets):
+                # a follower's incoming lanes: what its predecessor hands on
+                specs.append(r._spec(P=r._flat_width(b, incoming)
+                                     if r.kind in MAP_KINDS else 0))
+                incoming = r._carry_width(specs[-1])
+            specs = tuple(specs)
             consts_t = tuple(r.consts for r in members)
             states_t = tuple(r.state for r in members)
             step = _step_for("chain")
@@ -2821,7 +2923,7 @@ class DeviceOpRuntime:
             else:
                 r._placed_token = None
         handed = None      # (lanes, live) the previous stage handed on
-        for r, (hist, take, emitted) in zip(members, metrics):
+        for r, spec, (hist, take, emitted) in zip(members, specs, metrics):
             if r.kind == "fold" and handed is not None:
                 obs.count("device.fold_lanes", handed[0])
                 obs.count("device.fold_live", handed[1])
@@ -2833,6 +2935,7 @@ class DeviceOpRuntime:
                 r.op.workers[0].stats.processed_total += int(hist.sum())
             else:
                 take = _readback(take, "take")
+                r._count_map_pop(spec, take)
                 r._count_ring(hist)
                 r.lens += hist - take
                 if r.kind == "rows":    # every popped row was appended
@@ -2843,7 +2946,7 @@ class DeviceOpRuntime:
                 em = _readback(emitted, "emitted")
                 for w, worker in enumerate(r.op.workers):
                     worker.stats.emitted_total += int(em[w])
-                handed = (r.W * r._emit_bound(r.B), int(em.sum()))
+                handed = (r._carry_width(spec), int(em.sum()))
         for r in members[1:]:
             r._chain_serial = eng._super_serial
         if dc is not None:
@@ -2910,6 +3013,9 @@ class DeviceOpRuntime:
                 dc = (None if ch is None
                       else (ch.keys, ch.vals, ch.valid))
                 self._count_ingest(ch)
+                if self.kind in MAP_KINDS:
+                    spec = dataclasses.replace(spec, P=self._flat_width(
+                        b, 0 if ch is None else ch.n_live))
                 with obs.span("device.dispatch"):
                     res = step(spec, self.consts, self.state, dc,
                                np.int64(b))
@@ -2923,6 +3029,7 @@ class DeviceOpRuntime:
                 self._dispatched = True
                 hist = _readback(hist, "hist")
                 take = _readback(take, "take")
+                self._count_map_pop(spec, take)
                 self.edge.exchange.account(hist)
                 self.received += hist
                 self._count_ring(hist)

@@ -1000,3 +1000,265 @@ class TestDeviceCheckpoint:
                                           eb["routing"]["count"])
             np.testing.assert_array_equal(ea["sent_per_worker"],
                                           eb["sent_per_worker"])
+
+
+# --------------------------------------------------------------------- #
+# Flat map pops                                                          #
+# --------------------------------------------------------------------- #
+def _map_graph(backend=None, *, stages=("filter",), n=3000, num_keys=24,
+               num_workers=8, rate=8, map_rate=16, hot_frac=0.6,
+               controller=False, batch_ticks=4, **engine_kw):
+    """Source -> map stages -> GroupBy -> Sink over one key space.  Each
+    map stage serves ``map_rate`` a worker a tick while the source emits
+    ``num_workers * rate``, so the hot key's worker (``hot_frac`` of the
+    rows and more) backs up past its pop budget."""
+    keys, vals = _zipf_stream(n, num_keys, 5, hot_frac)
+    eng = Engine(partition_backend=backend, batch_ticks=batch_ticks,
+                 **engine_kw)
+    prev = eng.add_source(Source("src", keys, vals, num_workers * rate))
+    for i, stage in enumerate(stages):
+        if stage == "filter":
+            op = Filter(f"filter{i}", num_workers, map_rate,
+                        predicate=_half_pass if i else _all_pass)
+        else:
+            op = Project(f"proj{i}", num_workers, map_rate, fn=_proj_keep,
+                         preserves_keys=True)
+        eng.connect(prev, eng.add_op(op), num_keys)
+        prev = op
+    grp = eng.add_op(GroupByAgg("groupby", num_workers, rate))
+    sink = eng.add_op(Sink("sink", num_keys, snapshot_every=batch_ticks))
+    eng.connect(prev, grp, num_keys)
+    eng.connect(grp, sink, num_keys)
+    ctrl = None
+    if controller:
+        ctrl = eng.attach_controller(grp, ReshapeConfig(metric_period=4))
+    return eng, sink, grp, ctrl
+
+
+def _w1_graph(backend=None, **engine_kw):
+    from repro.dataflow import build_w1
+    wf = build_w1(scale=0.005, num_workers=6, service_rate=4, batch_ticks=4,
+                  snapshot_every=2, partition_backend=backend, **engine_kw)
+    return wf.engine, wf.sink, None, wf.controllers[0]
+
+
+class _FlatPops:
+    """Records every flat pop of the map stages: the host's bound, the
+    width it chose and whether a ring held more than the budget; the lanes
+    taken, whether a ring took none, whether a fused chain popped and
+    whether the pop crossed the end of a ring; and every chunk a map stage
+    hands a per-edge downstream."""
+
+    def __init__(self, monkeypatch):
+        from repro.dataflow import device as dev
+        self.widths, self.pops, self.chunks = [], [], []
+        self.in_chain = False
+        rt_cls = dev.DeviceOpRuntime
+        flat_width, count = rt_cls._flat_width, rt_cls._count_map_pop
+        dispatch_chain, stage = rt_cls._dispatch_chain, rt_cls.stage
+
+        def spy_width(rt, budget, incoming):
+            bound = min(rt.W * int(budget),
+                        int((rt.lens - rt.spilled_lens).sum())
+                        + int(incoming))
+            P = flat_width(rt, budget, incoming)
+            backlog = bool(((rt.lens - rt.spilled_lens) > budget).any())
+            self.widths.append((rt, int(budget), bound, P, backlog))
+            return P
+
+        def spy_count(rt, spec, take):
+            if rt.kind in dev.MAP_KINDS:
+                after = np.asarray(rt.state["head"])
+                before = after - take
+                self.pops.append(dict(
+                    rt=rt, P=spec.P, taken=int(take.sum()),
+                    idle=bool((take == 0).any()),
+                    chain=self.in_chain,
+                    wrapped=bool(((before % rt.cap) + take > rt.cap).any())))
+            return count(rt, spec, take)
+
+        def spy_chain(rt, members, budget):
+            self.in_chain = True
+            try:
+                return dispatch_chain(rt, members, budget)
+            finally:
+                self.in_chain = False
+
+        def spy_stage(rt, chunk):
+            if isinstance(chunk, dev.DeviceChunk):
+                self.chunks.append((rt, int(chunk.keys.shape[0])))
+            return stage(rt, chunk)
+
+        monkeypatch.setattr(rt_cls, "_flat_width", spy_width)
+        monkeypatch.setattr(rt_cls, "_count_map_pop", spy_count)
+        monkeypatch.setattr(rt_cls, "_dispatch_chain", spy_chain)
+        monkeypatch.setattr(rt_cls, "stage", spy_stage)
+
+
+def _full_width(rt, budget, incoming):
+    """The widest window a map stage may pop: the ``[W, B]`` rectangle."""
+    return rt.W * rt.B
+
+
+#: each case: (graph, its options, the run's script, what it reaches)
+_FLAT_CASES = {
+    # the hot worker's ring backs up past its budget
+    "filter-groupby-fused": (_map_graph, {}, None,
+                             {"chain", "backlog", "empty", "narrow"}),
+    # the group-by's controller rewrites, which unfuses the chain; the
+    # Filter keeps up, so its small rings wrap round
+    "filter-groupby-rewritten": (
+        _map_graph, {"controller": True, "map_rate": 64}, None,
+        {"chain", "apart", "wrap", "empty", "narrow"}),
+    "filter-probe": (_w1_graph, {}, None, {"chain", "apart", "narrow"}),
+    "filter-filter-fold": (
+        _map_graph, {"stages": ("filter", "filter"), "map_rate": 64}, None,
+        {"chain", "wrap", "empty", "narrow"}),
+    "project": (_map_graph, {"stages": ("filter", "project")}, None,
+                {"chain", "backlog", "narrow"}),
+    "flush-at-budget-0": (_map_graph, {}, "send-then-flush",
+                          {"chain", "flush0", "backlog"}),
+}
+
+
+def _run_flat_case(case, backend, **engine_kw):
+    build, kw, script, _ = _FLAT_CASES[case]
+    g = build(backend, **kw, **engine_kw)
+    eng = g[0]
+    if script == "send-then-flush":
+        # a chunk sent between super-ticks: staged on the device plane
+        # and flushed into the rings at budget 0 (a boundary's flush)
+        for _ in range(3):
+            eng.run_super_tick(eng._fusible_ticks(4))
+        rng = np.random.default_rng(9)
+        eng.edges[0].send((np.zeros(40, np.int64), rng.uniform(0, 10, 40)))
+        filt = eng.edges[0].dst
+        if filt.device is not None:
+            filt.device.flush_staged()
+    eng.run()
+    return g
+
+
+class TestFlatMapPop:
+    """A Filter / Project stage pops a flat, worker-major window sized from
+    the host-known live lanes; everything downstream sees the same live
+    lanes in the same order as from the ``[W, B]`` rectangle."""
+
+    @pytest.mark.parametrize("case", list(_FLAT_CASES))
+    def test_flat_pops_match_numpy_and_the_full_window(self, case,
+                                                        monkeypatch):
+        from repro import obs
+        from repro.dataflow import device as dev
+        a = _run_flat_case(case, "numpy")
+        before = dict(obs.counters())
+        with monkeypatch.context() as m:
+            spy = _FlatPops(m)
+            b = _run_flat_case(case, "pallas", device_executor="jit")
+        moved = {k: v - before.get(k, 0) for k, v in obs.counters().items()}
+        with monkeypatch.context() as m:
+            m.setattr(dev.DeviceOpRuntime, "_flat_width", _full_width)
+            c = _run_flat_case(case, "pallas", device_executor="jit")
+        assert all(e.device_plane == "jit" for e in b[0].edges)
+        _assert_runs_identical(a, b)
+        _mirrors_equal(a[0], b[0])
+        if a[3] is not None:
+            ev = lambda c: [(e.tick, e.kind, e.skewed, tuple(e.helpers))
+                            for e in c.events]
+            assert ev(a[3]) == ev(b[3])
+        # the float sums fold lane by lane: bit for bit as from the full
+        # window, and as the numpy plane's chunk-wise bincount to rounding
+        _assert_runs_identical(b, c)
+        np.testing.assert_array_equal(b[1].sums, c[1].sums)
+        if b[2] is not None:
+            for wb, wc in zip(b[2].workers, c[2].workers):
+                np.testing.assert_array_equal(wb.state.sums, wc.state.sums)
+        np.testing.assert_allclose(b[1].sums, a[1].sums, rtol=1e-12)
+
+        maps = [op.device for op in b[0].ops
+                if op.device is not None and op.device.kind in dev.MAP_KINDS]
+        assert maps and spy.pops
+        for rt, budget, bound, P, _ in spy.widths:
+            assert P == min(dev._pow2(bound), rt.W * rt.B) <= rt.W * rt.B
+        for p in spy.pops:
+            assert p["taken"] <= p["P"]
+        for rt in maps:
+            widths = {p["P"] for p in spy.pops if p["rt"] is rt}
+            down = rt.op.out_edge.dst.device
+            handed = {w for r, w in spy.chunks if r is down}
+            assert handed <= widths
+        # the counters are the flat windows and the lanes they took, and
+        # the lanes taken are the map stages' processed records
+        assert moved.get("device.map_pop_lanes", 0) == sum(
+            p["P"] for p in spy.pops)
+        assert moved.get("device.map_pop_live", 0) == sum(
+            p["taken"] for p in spy.pops) == sum(
+            w.stats.processed_total for rt in maps for w in rt.op.workers)
+        # a group-by's fold lanes: the chunks it ingests and, in a fused
+        # chain, the flat window of the map stage that hands it lanes
+        if b[2] is not None:
+            fold = b[2].device
+            up = next(rt for rt in maps if rt.op.out_edge.dst is b[2])
+            assert moved.get("device.fold_lanes", 0) == (
+                sum(w for r, w in spy.chunks if r is fold)
+                + sum(p["P"] for p in spy.pops
+                      if p["rt"] is up and p["chain"]))
+        # what each case is there to reach
+        floor = {rt: min(256, rt.W * rt.B) for rt in maps}
+        reached = {
+            "chain": any(p["chain"] for p in spy.pops),
+            "apart": any(not p["chain"] and p["taken"] for p in spy.pops),
+            "wrap": any(p["wrapped"] for p in spy.pops),
+            "backlog": any(w[4] for w in spy.widths),
+            # some ring empty while others pop
+            "empty": any(p["idle"] and p["taken"] for p in spy.pops),
+            "flush0": any(w[1] == 0 and w[3] == floor[w[0]]
+                          for w in spy.widths),
+            "narrow": any(p["P"] < p["rt"].W * p["rt"].B
+                          for p in spy.pops),
+        }
+        assert {k for k, v in reached.items() if v} >= _FLAT_CASES[case][3]
+        if case != "filter-probe":      # one worker holds most lanes
+            head = maps[0]
+            assert head.received.max() >= 0.6 * head.received.sum()
+
+
+class TestStepCompilesBound:
+    """The flat pop widths come from the data: a second execution on the
+    same data traces no new step."""
+
+    @pytest.mark.parametrize("cell,tiny", [
+        ("w1-join.ca-hot", {"scale": 0.012, "num_workers": 7}),
+        ("dsb-groupby.item-zipf2", {
+            "scale_factor": 0.002, "num_workers": 8, "service_rate": 8,
+            "filter_rate": 64, "batch_ticks": 8, "snapshot_every": 8}),
+    ])
+    def test_second_execution_traces_nothing(self, cell, tiny, monkeypatch):
+        import os
+
+        from repro.dataflow import device as dev
+        monkeypatch.syspath_prepend(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+        from bench import harness
+        c = harness.Cell(cell, 2**31 + 5, executor="jit",
+                         overrides={"config": tiny})
+        traced = []
+        note = dev._note_trace
+        monkeypatch.setattr(dev, "_note_trace", lambda kind, spec, args: (
+            traced.append((kind, spec)), note(kind, spec, args)))
+        widths = []
+        flat_width = dev.DeviceOpRuntime._flat_width
+        monkeypatch.setattr(dev.DeviceOpRuntime, "_flat_width",
+                            lambda rt, b, n: widths.append(
+                                flat_width(rt, b, n)) or widths[-1])
+        for run in range(2):
+            traced.clear()
+            eng = c.build()[0]
+            eng.run(c.max_ticks)
+            assert eng.done()
+            if run == 0:
+                assert any(k in ("filter", "chain") for k, _ in traced)
+                first = list(widths)
+            else:
+                assert widths == first
+            widths.clear()
+        assert traced == []
